@@ -18,10 +18,13 @@ for everyone.  Reported per row:
 
 ``--sharded`` additionally compares barrier vs gang-scheduled cohorts
 on the sharded LM TRAINER (DESIGN.md §10) by driving
-``repro.launch.async_sharded_train`` in subprocesses (the host mesh
-needs XLA_FLAGS set before jax imports, which this process has already
-done) and asserts the flight-buffered scheduler beats the barrier in
-virtual wall-clock on a heterogeneous fleet.
+``repro.launch.async_sharded_train --smoke`` in subprocesses (its
+8-device host mesh needs XLA_FLAGS set before jax imports, which this
+process has already done) and asserts the flight-buffered scheduler
+beats the barrier in virtual wall-clock on a heterogeneous fleet.  The
+cell measures virtual time, not the device, so the children are pinned
+to the CPU: none of them ever needs an accelerator this process may
+hold.
 """
 from __future__ import annotations
 
@@ -119,11 +122,12 @@ def main(quick: bool = True):
 def _run_sharded_cell(buffer: int, rounds: int, sigma: float) -> dict:
     """One barrier-vs-gang cell on the LM trainer, via the CLI in a
     subprocess (a fresh process so --smoke can set the host-mesh
-    XLA_FLAGS before jax initializes)."""
+    XLA_FLAGS before jax initializes), pinned to the CPU."""
     import re
     import subprocess
 
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = (os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
         + (os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""))

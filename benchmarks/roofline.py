@@ -3,9 +3,12 @@
 For each (arch x input-shape x mesh) JSON produced by
 ``repro.launch.dryrun`` we derive the three roofline terms in seconds:
 
-    compute    = HLO_FLOPs_per_device / peak_FLOPs          (197 TFLOP/s bf16)
-    memory     = HLO_bytes_per_device / HBM_bw              (819 GB/s)
-    collective = sum_links collective_bytes / link_bw       (~50 GB/s/link)
+    compute    = HLO_FLOPs_per_device / peak_FLOPs
+    memory     = HLO_bytes_per_device / HBM_bw
+    collective = sum_links collective_bytes / link_bw
+
+with the target chip's published peaks looked up by ``device_kind`` in
+:data:`PEAKS` (an unknown device is an error, never a default).
 
 ``cost_analysis`` supplies per-device FLOPs and bytes; collective bytes
 are parsed from the SPMD-partitioned HLO (dryrun.collective_bytes) —
@@ -28,9 +31,27 @@ from typing import Dict, List, Optional
 
 from repro.models.registry import ARCH_IDS, INPUT_SHAPES, get_config
 
-PEAK_FLOPS = 197e12          # bf16 per chip (v5e)
-HBM_BW = 819e9               # bytes/s per chip
-LINK_BW = 50e9               # bytes/s per ICI link
+# Published per-chip peaks keyed by jax's ``device_kind``.
+PEAKS: Dict[str, Dict] = {
+    "TPU v5 lite": dict(
+        flops_bf16=197e12, hbm_bw=819e9,
+        link_bw=50e9,     # 1,600 Gbit/s of interconnect over 4 links
+        source='Google Cloud documentation, "TPU v5e": 197 TFLOP/s '
+               'bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s '
+               'chip-to-chip interconnect'),
+}
+# The chip the dry-run lowers its 16x16 / 2x16x16 pod meshes for.
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Dict:
+    """The published peaks of ``device_kind``; raises for a device the
+    table does not list."""
+    if device_kind not in PEAKS:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; add them to PEAKS with their "
+                         f"source (have {sorted(PEAKS)})")
+    return PEAKS[device_kind]
 
 
 def active_params(arch_id: str) -> float:
@@ -107,10 +128,11 @@ def roofline_terms(rec: Dict, chips: int) -> Dict:
       parallel collectives are counted once per step (lower bound),
       noted per pair via hlo_undercount.
     """
+    pk = peaks(rec.get("device_kind", DRYRUN_DEVICE_KIND))
     n_data = 32 if rec["mesh"] == "2x16x16" else 16
     mf = model_flops(rec["arch"], rec["shape"])
-    comp = mf / chips / PEAK_FLOPS
-    mem = rec["bytes_per_device"] / HBM_BW
+    comp = mf / chips / pk["flops_bf16"]
+    mem = rec["bytes_per_device"] / pk["hbm_bw"]
     coll = rec.get("collectives", {})
     ring = (n_data - 1) / n_data
     coll_bytes_link = (
@@ -119,7 +141,7 @@ def roofline_terms(rec: Dict, chips: int) -> Dict:
         + coll.get("all-reduce", 0) * 2 * ring
         + coll.get("all-to-all", 0) / n_data
         + coll.get("collective-permute", 0))
-    collective = coll_bytes_link / LINK_BW
+    collective = coll_bytes_link / pk["link_bw"]
     dom = max(("compute", comp), ("memory", mem),
               ("collective", collective), key=lambda kv: kv[1])
     # how much of compiled compute the HLO reports vs analytic — ≈1/L for

@@ -50,7 +50,10 @@ def main() -> None:
     # the generic per-suite dump below must not clobber it
     self_managed = {"kernels"}
     if args.only:
-        suites = {k: v for k, v in suites.items() if k == args.only}
+        if args.only not in suites:
+            sys.exit(f"unknown benchmark {args.only!r}; choose from "
+                     f"{sorted(suites)}")
+        suites = {args.only: suites[args.only]}
     os.makedirs(args.out, exist_ok=True)
     failures = []
     for name, fn in suites.items():

@@ -8,6 +8,13 @@ Every ``pl.pallas_call`` in ``kernels/`` encodes an implicit contract:
 * the number of runtime operands matches ``in_specs`` (plus the
   scalar-prefetch operands, which come first);
 * ``out_specs`` and ``out_shape`` agree in arity;
+* a block's last two dims form a plane the TPU compiler accepts: it
+  requires multiples of (8, 128) or the array's own dims, so a block
+  ``(..., 1, X)`` compiles only over an array whose second-minor dim is
+  itself 1.  Interpret mode never checks this; the paged-attention
+  ``(1, rows, 1, hd)`` and BlockRandK ``(1, bs)`` row blocks passed
+  every interpret-mode test and were refused by the compiler.  View the
+  array so the block's last two dims are a full plane;
 * the per-grid-step VMEM footprint — Σ block-shape bytes over
   in/out specs and scratch — fits the module's own budget: a
   ``*VMEM_BUDGET*`` constant when the module defines one, else the
@@ -186,9 +193,13 @@ class _Resolver:
                            if "out_specs" in kwargs else UNKNOWN),
                 node=call)
         # module-local helper with a single return of resolvable values
+        # (a helper that returns from several branches is not followed:
+        # which branch runs depends on values the resolver lacks)
         local_fn = self.mod.functions.by_qualname.get(name) \
             if "." not in name else None
-        if local_fn is not None and depth < self.MAX_DEPTH:
+        if local_fn is not None and depth < self.MAX_DEPTH \
+                and sum(isinstance(n, ast.Return)
+                        for n in ast.walk(local_fn)) == 1:
             bound: Dict[str, Any] = {}
             params = _astutil.param_names(local_fn)
             for pname, arg in zip(params, call.args):
@@ -328,6 +339,16 @@ class PallasContractChecker(Checker):
                         + (f" + {nsp} scalar-prefetch operand(s)"
                            if nsp else "")
                         + f" = {want} expected")
+            if isinstance(spec.shape, tuple) and len(spec.shape) >= 2 \
+                    and spec.shape[-2] == 1 and spec.shape[-1] != 1:
+                yield mod.finding(
+                    self.id, self.severity, spec.node,
+                    f"block {_fmt_shape(spec.shape)} has 1 in its "
+                    "second-minor dim: the TPU compiler needs the last "
+                    "two block dims to be multiples of (8, 128) or the "
+                    "array's own dims, so this compiles only over an "
+                    "array whose second-minor dim is 1 — view the array "
+                    "so the block's last two dims are a full plane")
             if spec.index_map is not None \
                     and isinstance(spec.shape, tuple):
                 ret = _lambda_return_arity(spec.index_map)

@@ -1,10 +1,8 @@
-"""jax version-compat shims.
+"""The one route to the jax sharding APIs the engines use.
 
-The production target is a recent jax (``jax.shard_map``,
-``jax.sharding.AxisType``, ``jax.set_mesh``); the pinned container image
-ships an older one where those live under ``jax.experimental`` or do not
-exist.  Everything that touches the moved APIs goes through here so the
-rest of the codebase is written against the new names only.
+Everything that builds a mesh, enters one, or maps a function over one
+goes through here, so the settings the engines rely on (replication
+checking off, Auto axis types) live in one place.
 """
 from __future__ import annotations
 
@@ -17,36 +15,21 @@ __all__ = ["shard_map", "make_mesh", "use_mesh"]
 
 def shard_map(f, *, mesh, in_specs, out_specs):
     """``jax.shard_map`` with replication checking off (per-node
-    randomness makes outputs intentionally non-replicated).  Falls back
-    to ``jax.experimental.shard_map`` (spelled ``check_rep``) on older
-    jax."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:  # pre-rename releases call it check_rep
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    randomness makes outputs intentionally non-replicated)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
-def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types where supported (newer jax
-    defaults to Explicit sharding under which the engine's untyped specs
-    would be rejected); plain mesh on older jax."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                             axis_types=(axis_type.Auto,) * len(axis_names))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
+              devices=None):
+    """``jax.make_mesh`` with Auto axis types (jax defaults to Explicit
+    sharding, under which the engine's untyped specs would be
+    rejected).  ``devices`` defaults to ``jax.devices()``."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names), devices=devices)
 
 
 def use_mesh(mesh) -> Any:
-    """Context manager making ``mesh`` the ambient mesh:
-    ``jax.set_mesh`` where it exists, the mesh's own context manager
-    otherwise."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager making ``mesh`` the ambient mesh."""
+    return jax.set_mesh(mesh)

@@ -55,6 +55,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.randk import block_spec, block_view
+
 Array = jax.Array
 
 LANES = 128
@@ -468,25 +470,27 @@ def dasha_payload_blocks_pallas(gn: Array, go: Array, h: Array, gi: Array,
     bs = block_size
     nb = -(-d // bs)
     pad = nb * bs - d
+    rows, lanes = block_view(bs)
 
     def prep(x):
-        return jnp.pad(x, (0, pad)).reshape(nb, bs)
+        return jnp.pad(x, (0, pad)).reshape(nb, rows, lanes)
 
     gn2, go2, h2, gi2 = map(prep, (gn, go, h, gi))
-    row = pl.BlockSpec((1, bs), lambda i, idx: (idx[i], 0))
+    row = block_spec(bs, lambda i, idx: (idx[i], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(kb,),
         in_specs=[row, row, row, row],
-        out_specs=pl.BlockSpec((1, bs), lambda i, idx: (i, 0)),
+        out_specs=block_spec(bs, lambda i, idx: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_payload_blocks_kernel, b=b, a=a, pa=pa,
                           scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((kb, bs), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((kb, rows, lanes), jnp.float32),
         interpret=interpret,
     )(block_idx.astype(jnp.int32), gn2, go2, h2, gi2)
+    return out.reshape(kb, bs)
 
 
 def _page_payload_blocks_kernel(idx_ref, coin_ref, gn_ref, go_ref, bn_ref,
@@ -527,24 +531,26 @@ def dasha_page_payload_blocks_pallas(gn: Array, go: Array, bn: Array,
     bs = block_size
     nb = -(-d // bs)
     pad = nb * bs - d
+    rows, lanes = block_view(bs)
 
     def prep(x):
-        return jnp.pad(x, (0, pad)).reshape(nb, bs)
+        return jnp.pad(x, (0, pad)).reshape(nb, rows, lanes)
 
     gn2, go2, bn2, bo2, h2, gi2 = map(prep, (gn, go, bn, bo, h, gi))
     coin2 = jnp.reshape(coin.astype(jnp.float32), (1, 1))
-    row = pl.BlockSpec((1, bs), lambda i, idx: (idx[i], 0))
+    row = block_spec(bs, lambda i, idx: (idx[i], 0, 0))
     scalar = pl.BlockSpec((1, 1), lambda i, idx: (0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(kb,),
         in_specs=[scalar, row, row, row, row, row, row],
-        out_specs=pl.BlockSpec((1, bs), lambda i, idx: (i, 0)),
+        out_specs=block_spec(bs, lambda i, idx: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_page_payload_blocks_kernel, b=b, a=a, pa=pa,
                           p_page=p_page, scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((kb, bs), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((kb, rows, lanes), jnp.float32),
         interpret=interpret,
     )(block_idx.astype(jnp.int32), coin2, gn2, go2, bn2, bo2, h2, gi2)
+    return out.reshape(kb, bs)

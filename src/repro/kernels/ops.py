@@ -1,8 +1,8 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels run in interpret mode (the body
-executes in Python, numerics identical); on TPU set
-``REPRO_PALLAS_INTERPRET=0`` or pass ``interpret=False``.
+On TPU the kernels compile for the chip; on any other backend they run
+in interpret mode (the body executes in Python, numerics identical).
+``REPRO_PALLAS_INTERPRET`` or ``interpret=`` overrides the choice.
 """
 from __future__ import annotations
 

@@ -35,8 +35,9 @@ Two kernels share the page-table-walk machinery:
   K/V never exist, in HBM *or* VMEM.
 
 VMEM budget (mirrors ``buffered_commit_pallas``): one grid step holds
-one K tile + V tile (GQA: a single kv head; MLA: the latent + rope
-rows), the query block, and the accumulators.  Pages larger than the
+one K tile + V tile (GQA: a single kv head's ``(rows, hd)`` plane of
+the head-major pool view; MLA: the latent + rope rows), the query
+block, and the accumulators.  Pages larger than the
 row budget are walked in sub-page tiles of ``_page_tile_rows`` rows (a
 multiple of 8 f32 sublanes) so the working set stays inside
 ``PAGE_VMEM_BUDGET`` regardless of ``page_size``.
@@ -86,15 +87,16 @@ def _page_tile_rows(page_size: int, row_bytes: int,
 
 def paged_attention_vmem_bytes(page_size: int, kvh: int, hd: int,
                                num_q_heads: int) -> int:
-    """Worst-case VMEM bytes of one grid step (f32): K/V tile + query +
-    accumulators — the number the §11 budget table reports.  The fused
-    grid walks one kv head per step, so the tile is ``rows * hd``
-    regardless of ``kvh``."""
+    """VMEM bytes of one decode grid step (f32): K/V tiles, the query
+    and output blocks and the accumulators — the number the §11 budget
+    table reports.  The fused grid walks one kv head per step, so the
+    tile is ``rows * hd`` regardless of ``kvh``, and the query rows are
+    that head's ``num_q_heads / kvh`` group (times C for a chunked
+    pass)."""
     rows = _page_tile_rows(page_size, 2 * hd * 4)
     tile = 2 * rows * hd * 4
-    q = num_q_heads * hd * 4
-    acc = num_q_heads * hd * 4 + 2 * num_q_heads * 4
-    return tile + q + acc
+    g = num_q_heads // kvh
+    return tile + 3 * g * hd * 4 + 2 * g * 4
 
 
 # ----------------------------------------------------------------------
@@ -180,7 +182,8 @@ def _paged_attention_batched_kernel(table_ref, start_ref, qlen_ref,
                                     q_ref, k_ref, v_ref, out_ref,
                                     acc_ref, m_ref, l_ref, *,
                                     page_size: int, tile_rows: int,
-                                    window: int | None, scale: float):
+                                    group: int, window: int | None,
+                                    scale: float):
     b = pl.program_id(0)
     j = pl.program_id(2)
     n_j = pl.num_programs(2)
@@ -193,36 +196,36 @@ def _paged_attention_batched_kernel(table_ref, start_ref, qlen_ref,
         m_ref[...] = jnp.full_like(m_ref, -1e30)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    C = q_ref.shape[1]
+    # one kv head's query rows, query-major: row r is query r // group
+    CG = q_ref.shape[2]
     start = start_ref[b]
     base = (j // tiles_per_page) * page_size \
         + (j % tiles_per_page) * tile_rows
     pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, tile_rows), 1)
-    q_pos = start + jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
-    valid = pos < q_pos + 1                          # (C, tile_rows)
+    q_pos = start + jax.lax.broadcasted_iota(jnp.int32, (CG, 1), 0) // group
+    valid = pos < q_pos + 1                          # (CG, tile_rows)
     if window is not None:
         valid &= pos > q_pos - window
 
-    hd = k_ref.shape[3]
-    q = q_ref[0, :, 0].astype(jnp.float32)           # (C, G, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)           # (tile_rows, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    s = jnp.einsum("cgh,sh->cgs", q, k) * scale      # (C, G, tile_rows)
-    s = jnp.where(valid[:, None], s, -1e30)
+    q = q_ref[0, 0]                                  # (CG, hd)
+    k = k_ref[0, 0]                                  # (tile_rows, hd)
+    v = v_ref[0, 0]
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    s = jnp.where(valid, s, -1e30)                   # (CG, tile_rows)
 
-    m_old = m_ref[...]                               # (C, G)
-    m_new = jnp.maximum(m_old, jnp.max(s, axis=-1))
+    m_old = m_ref[...]                               # (CG, 1)
+    m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
     corr = jnp.exp(m_old - m_new)
-    p = jnp.exp(s - m_new[..., None])
+    p = jnp.exp(s - m_new)
     m_ref[...] = m_new
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-    acc_ref[...] = (acc_ref[...] * corr[..., None]
-                    + jnp.einsum("cgs,sh->cgh", p, v))
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+        p, v, preferred_element_type=jnp.float32)
 
     @pl.when(j == n_j - 1)
     def _():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[..., None]
-        out_ref[0, :, 0] = out.reshape(C, acc_ref.shape[1], hd)
+        out_ref[0, 0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -237,47 +240,61 @@ def paged_attention_batched_pallas(q: Array, k_pages: Array,
     page table routes physical pages into VMEM, and the online-softmax
     state lives in scratch across each (slot, head) walk.  Walking one
     kv head per step keeps the tile at ``rows * hd`` bytes independent
-    of ``kvH``, so big-GQA configs stay under the VMEM budget."""
+    of ``kvH``, so big-GQA configs stay under the VMEM budget.
+
+    The kernel reads the pool head-major, ``(NP, kvH, P, hd)``, so a
+    K/V block ``(1, 1, rows, hd)`` ends in the ``(rows, hd)`` plane the
+    TPU tiles: a ``(1, rows, 1, hd)`` block of the ``(NP, P, kvH, hd)``
+    pool puts a 1 over ``kvH`` in the second-minor dim, which the TPU
+    compiler refuses.  The relayout fuses with the f32 cast of the
+    pool.  Each slot's queries of one kv head are a ``(C * G, hd)``
+    matrix (query-major), so scores and the value product are plain
+    2-D matmuls."""
     B, C, H, hd = q.shape
     NP, P, kvh, _ = k_pages.shape
     M = page_table.shape[1]
     G = H // kvh
+    CG = C * G
     tile_rows = _page_tile_rows(P, 2 * hd * 4)
     tiles_per_page = P // tile_rows
     scale = 1.0 / math.sqrt(hd)
 
-    q5 = q.reshape(B, C, kvh, G, hd).astype(jnp.float32)
+    qh = (q.astype(jnp.float32).reshape(B, C, kvh, G, hd)
+          .transpose(0, 2, 1, 3, 4).reshape(B, kvh, CG, hd))
+    kh = k_pages.astype(jnp.float32).transpose(0, 2, 1, 3)
+    vh = v_pages.astype(jnp.float32).transpose(0, 2, 1, 3)
 
     def page_idx(b, h, j, table, start_, qlens_):
-        return (table[b, (j * tile_rows) // P], (j % tiles_per_page), h, 0)
+        return (table[b, j // tiles_per_page], h, j % tiles_per_page, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, kvh, M * tiles_per_page),
         in_specs=[
-            pl.BlockSpec((1, C, 1, G, hd),
-                         lambda b, h, j, t, s, ql: (b, 0, h, 0, 0)),
-            pl.BlockSpec((1, tile_rows, 1, hd), page_idx),
-            pl.BlockSpec((1, tile_rows, 1, hd), page_idx),
+            pl.BlockSpec((1, 1, CG, hd),
+                         lambda b, h, j, t, s, ql: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, tile_rows, hd), page_idx),
+            pl.BlockSpec((1, 1, tile_rows, hd), page_idx),
         ],
-        out_specs=pl.BlockSpec((1, C, 1, G, hd),
-                               lambda b, h, j, t, s, ql: (b, 0, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, CG, hd),
+                               lambda b, h, j, t, s, ql: (b, h, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((C, G, hd), jnp.float32),
-            pltpu.VMEM((C, G), jnp.float32),
-            pltpu.VMEM((C, G), jnp.float32),
+            pltpu.VMEM((CG, hd), jnp.float32),
+            pltpu.VMEM((CG, 1), jnp.float32),
+            pltpu.VMEM((CG, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_paged_attention_batched_kernel, page_size=P,
-                          tile_rows=tile_rows, window=window, scale=scale),
+                          tile_rows=tile_rows, group=G, window=window,
+                          scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C, kvh, G, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, kvh, CG, hd), jnp.float32),
         interpret=interpret,
     )(page_table.astype(jnp.int32), start.astype(jnp.int32),
-      q_lens.astype(jnp.int32), q5, k_pages.astype(jnp.float32),
-      v_pages.astype(jnp.float32))
-    return out.reshape(B, C, H, hd)
+      q_lens.astype(jnp.int32), qh, kh, vh)
+    return (out.reshape(B, kvh, C, G, hd).transpose(0, 2, 1, 3, 4)
+            .reshape(B, C, H, hd))
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))

@@ -27,6 +27,28 @@ from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
+LANES = 128
+
+
+def block_view(bs: int) -> Tuple[int, int]:
+    """The (rows, lanes) tile one ``bs``-sized block is viewed as, so a
+    block's BlockSpec satisfies the TPU tiling rule: the last two block
+    dims must be multiples of (8, 128) or equal the array's own dims.
+    An ``(nb, bs)`` array with ``(1, bs)`` row blocks breaks it (a
+    second-minor block dim of 1 over ``nb`` rows); viewed as
+    ``(nb, rows, lanes)`` with ``(1, rows, lanes)`` blocks, the last two
+    block dims are the array's own.  Lane-aligned blocks use full
+    128-lane rows; any other size is one row of ``bs``."""
+    if bs % LANES == 0:
+        return bs // LANES, LANES
+    return 1, bs
+
+
+def block_spec(bs: int, index_map) -> "pl.BlockSpec":
+    """BlockSpec of one block in the :func:`block_view` layout."""
+    rows, lanes = block_view(bs)
+    return pl.BlockSpec((1, rows, lanes), index_map)
+
 
 def _gather_kernel(idx_ref, x_ref, out_ref, *, scale: float):
     # x_ref block is chosen by the index_map via idx_ref (scalar prefetch);
@@ -41,19 +63,22 @@ def block_gather_pallas(x_blocks: Array, block_idx: Array, *, k_blocks: int,
     """x_blocks: (nb, bs) f32; block_idx: (k_blocks,) int32 ->
     (k_blocks, bs) = x_blocks[block_idx] * scale."""
     nb, bs = x_blocks.shape
+    rows, lanes = block_view(bs)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(k_blocks,),
-        in_specs=[pl.BlockSpec((1, bs), lambda i, idx: (idx[i], 0))],
-        out_specs=pl.BlockSpec((1, bs), lambda i, idx: (i, 0)),
+        in_specs=[block_spec(bs, lambda i, idx: (idx[i], 0, 0))],
+        out_specs=block_spec(bs, lambda i, idx: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_gather_kernel, scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((k_blocks, bs), x_blocks.dtype),
+        out_shape=jax.ShapeDtypeStruct((k_blocks, rows, lanes),
+                                       x_blocks.dtype),
         interpret=interpret,
-    )(block_idx, x_blocks)
+    )(block_idx, x_blocks.reshape(nb, rows, lanes))
+    return out.reshape(k_blocks, bs)
 
 
 def _scatter_kernel(idx_ref, vals_ref, base_ref, out_ref):
@@ -69,20 +94,23 @@ def block_scatter_pallas(base_blocks: Array, vals: Array, block_idx: Array,
     selected rows are distinct (RandK samples without replacement)."""
     nb, bs = base_blocks.shape
     kb = vals.shape[0]
+    rows, lanes = block_view(bs)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(kb,),
         in_specs=[
-            pl.BlockSpec((1, bs), lambda i, idx: (i, 0)),       # vals
-            pl.BlockSpec((1, bs), lambda i, idx: (idx[i], 0)),  # base row
+            block_spec(bs, lambda i, idx: (i, 0, 0)),        # vals
+            block_spec(bs, lambda i, idx: (idx[i], 0, 0)),   # base row
         ],
-        out_specs=pl.BlockSpec((1, bs), lambda i, idx: (idx[i], 0)),
+        out_specs=block_spec(bs, lambda i, idx: (idx[i], 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _scatter_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nb, bs), base_blocks.dtype),
+        out_shape=jax.ShapeDtypeStruct((nb, rows, lanes), base_blocks.dtype),
         interpret=interpret,
         input_output_aliases={2: 0},   # alias base (input 2) -> out 0
-    )(block_idx, vals, base_blocks)
+    )(block_idx, vals.reshape(kb, rows, lanes),
+      base_blocks.reshape(nb, rows, lanes))
+    return out.reshape(nb, bs)

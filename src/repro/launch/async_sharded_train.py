@@ -12,7 +12,9 @@ cohort, buffers it by virtual arrival time, and commits the first-K
 cohorts with staleness weights.  ``--buffer 0`` waits for every
 outstanding cohort — the barrier baseline the bench compares against.
 ``--smoke`` shrinks to the reduced config on an 8-device host mesh
-(same code path end to end).  The final line is machine-readable:
+(same code path end to end); without it the full config runs on an
+``(n, 1)`` mesh of the devices present.  The final line is
+machine-readable:
 
     RESULT t_virtual=<s> loss=<f> grad_norm=<f> commits=<n> ...
 """
@@ -67,8 +69,9 @@ def main():
     from repro.data.synthetic import DataConfig, make_batch
     from repro.fl import (CohortConfig, PoissonAvailability, make_latency,
                           train_async)
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import (data_axes_of, make_host_mesh,
-                                   make_production_mesh, num_nodes)
+                                   make_device_mesh, num_nodes)
     from repro.models import Model, get_config, get_smoke_config
     from repro.models.registry import INPUT_SHAPES
     from repro.obs import start_run
@@ -76,12 +79,13 @@ def main():
     from repro.training.optim import paper_server
     from repro.training.trainer import Trainer, TrainerConfig
 
+    enable_compile_cache()
     if args.smoke:
         mesh = make_host_mesh(data=4, model=2)
         cfg = get_smoke_config(args.arch).with_overrides(dtype="float32")
         seq, gbatch = 64, 8
     else:
-        mesh = make_production_mesh()
+        mesh = make_device_mesh()
         cfg = get_config(args.arch)
         shp = INPUT_SHAPES["train_4k"]
         seq, gbatch = shp.seq_len, shp.global_batch

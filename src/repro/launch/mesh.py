@@ -1,16 +1,20 @@
-"""Production meshes.
+"""Meshes.
 
 Defined as FUNCTIONS (not module-level constants) so importing this
 module never touches jax device state — required because the dry-run
-must set XLA_FLAGS before any jax initialization.
+and the ``--smoke`` CLIs must set XLA_FLAGS before any jax
+initialization.
 
-Mesh construction goes through :mod:`repro.compat` so the same code
-runs on jax versions with and without ``jax.sharding.AxisType``.
+The launch CLIs build :func:`make_device_mesh` from the devices
+present (one DASHA-PP node per device); the 16x16 pod layout is built
+only where it is asked for by name (``launch.train --mesh pod``, the
+dry-run).
 """
 from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 from jax.sharding import Mesh
 
 from repro.compat import make_mesh
@@ -21,6 +25,13 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return make_mesh(shape, axes)
+
+
+def make_device_mesh(devices=None) -> Mesh:
+    """``(n, 1)`` over ``("data", "model")`` from the ``n`` devices
+    present: ``(1, 1)`` on one chip, ``(4, 1)`` on a four-chip host."""
+    devs = list(jax.devices() if devices is None else devices)
+    return make_mesh((len(devs), 1), ("data", "model"), devices=devs)
 
 
 def data_axes_of(mesh: Mesh) -> Tuple[str, ...]:

@@ -6,7 +6,7 @@
 
 ``--smoke`` (the default; disable with ``--no-smoke``) uses the reduced
 config on a forced host platform.  ``--no-smoke`` routes through the
-production path: the 16x16 v5e mesh from launch/mesh.py, params
+production path: an ``(n, 1)`` mesh of the devices present, params
 initialized directly into their param_specs_like shardings, and decode
 state placed via launch/specs (``decode_state_specs`` for the dense
 engine, ``paged_state_specs`` for the page pool — pages replicate over
@@ -39,7 +39,8 @@ def main():
     ap.add_argument("--pages", type=int, default=0,
                     help="paged engine: pool pages (0 = dense-equivalent)")
     ap.add_argument("--no-kernel", action="store_true",
-                    help="paged engine: force the jnp gather read")
+                    help="paged engine: the jnp gather read instead of "
+                         "the Pallas kernel (interpret mode off-TPU)")
     ap.add_argument("--prefill-chunk-tokens", type=int, default=None,
                     help="paged engine: fold prompt prefill into the fused "
                          "decode pass, this many prompt tokens per pass "
@@ -61,10 +62,12 @@ def main():
 
     import jax
     import numpy as np
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import Model, get_config, get_smoke_config
     from repro.obs import start_run
     from repro.serving import DecodeServer, PagedEngine, Request
 
+    enable_compile_cache()
     obsrun = start_run(trace_out=args.trace_out,
                        metrics_out=args.metrics_out,
                        meta={"cli": "serve", "engine": args.engine,
@@ -79,12 +82,12 @@ def main():
     if args.smoke:
         params = model.init_params(jax.random.key(0))
     else:
-        # production path: params born sharded on the v5e pod mesh
-        from repro.launch.mesh import make_production_mesh
+        # production path: params born sharded on the mesh
+        from repro.launch.mesh import make_device_mesh
         from repro.launch.specs import (decode_state_specs,
                                         paged_state_specs, to_shardings)
         from repro.models import param_specs_like
-        mesh = make_production_mesh()
+        mesh = make_device_mesh()
         shapes = jax.eval_shape(model.init_params, jax.random.key(0))
         shardings = to_shardings(param_specs_like(shapes, mesh), mesh)
         params = jax.jit(model.init_params,
@@ -101,8 +104,7 @@ def main():
                              max_seq_len=args.max_seq,
                              page_size=args.page_size,
                              num_pages=args.pages or None,
-                             use_kernel=not args.no_kernel and
-                             jax.default_backend() == "tpu",
+                             use_kernel=not args.no_kernel,
                              prefill_chunk_tokens=args.prefill_chunk_tokens,
                              bucket_sizes=buckets)
 
@@ -130,7 +132,9 @@ def main():
     dt = time.time() - t0
     tot = sum(len(r.generated) for r in done)
     print(f"served {len(done)} requests, {tot} tokens, "
-          f"{tot/dt:.1f} tok/s (engine={args.engine}, batch={args.batch})")
+          f"{tot/dt:.1f} tok/s (engine={args.engine}, batch={args.batch}"
+          + (f", kernel={not args.no_kernel})" if args.engine == "paged"
+             else ")"))
     if args.engine == "paged":
         m = server.metrics()
         print(f"  prefill_forwards={m['prefill_forwards']} "

@@ -1,11 +1,13 @@
 """Production training entrypoint:
 
     python -m repro.launch.train --arch granite-3-2b --shape train_4k \
-        [--multi-pod] [--steps N] [--smoke]
+        [--mesh {devices,pod,multi-pod}] [--steps N] [--smoke]
 
-On real TPU hardware this builds the production mesh and runs the full
-config; ``--smoke`` (the CPU path) shrinks to the reduced config on a
-host mesh — same code path end to end.
+Without ``--smoke`` this runs the full config on a mesh of the devices
+present (``(n, 1)`` over ``data, model``: one DASHA-PP node per
+device); ``--mesh pod`` / ``multi-pod`` ask for the 16x16 / 2x16x16
+production layouts by name.  ``--smoke`` (the CPU path) shrinks to the
+reduced config on an 8-device host mesh — same code path end to end.
 """
 import argparse
 import os
@@ -15,7 +17,10 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", default="train_4k")
-    ap.add_argument("--multi-pod", action="store_true")
+    ap.add_argument("--mesh", default="devices",
+                    choices=["devices", "pod", "multi-pod"],
+                    help="devices: (n, 1) over the devices present; "
+                         "pod / multi-pod: the 16x16 / 2x16x16 layouts")
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--p-a", type=float, default=0.5)
@@ -49,11 +54,13 @@ def main():
 
     import jax
     from repro.compat import use_mesh
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.obs import start_run
     from repro.core.sharded import ShardedDashaConfig
     from repro.data.synthetic import DataConfig, make_batch
-    from repro.launch.mesh import (data_axes_of, make_host_mesh,
-                                   make_production_mesh, num_nodes)
+    from repro.launch.mesh import (data_axes_of, make_device_mesh,
+                                   make_host_mesh, make_production_mesh,
+                                   num_nodes)
     from repro.models import Model, get_config, get_smoke_config
     from repro.models.registry import INPUT_SHAPES
     from repro.training.loop import train
@@ -61,12 +68,14 @@ def main():
     from repro.training.optim import adamw_server, paper_server
     from repro.training.trainer import Trainer, TrainerConfig
 
+    enable_compile_cache()
     if args.smoke:
         mesh = make_host_mesh(data=4, model=2)
         cfg = get_smoke_config(args.arch).with_overrides(dtype="float32")
         seq, gbatch = 64, 8
     else:
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
+        mesh = (make_device_mesh() if args.mesh == "devices" else
+                make_production_mesh(multi_pod=args.mesh == "multi-pod"))
         cfg = get_config(args.arch)
         shp = INPUT_SHAPES[args.shape]
         seq, gbatch = shp.seq_len, shp.global_batch

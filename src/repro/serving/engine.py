@@ -241,6 +241,19 @@ class PagedEngine:
         self.decode_seconds = 0.0   # wall time of PURE decode passes
         self.decode_tokens = 0      # tokens generated in those passes
 
+    def lower_fused_pass(self, chunk: int, width: int):
+        """Lower the fused pass for ``chunk`` tokens per slot over a
+        ``width``-page table — the shapes :meth:`step` feeds it.
+        Compiling the result also warms the pass's jit cache, so the
+        run that follows times passes, not compiles."""
+        state = PagedDecodeState(
+            caches=self._caches,
+            page_table=jnp.zeros((self.batch, width), jnp.int32),
+            seq_lens=jnp.zeros((self.batch,), jnp.int32))
+        return self._fused_fn.lower(
+            self.params, jnp.zeros((self.batch, chunk), jnp.int32), state,
+            jnp.zeros((self.batch,), jnp.int32))
+
     def place_caches(self, shardings) -> None:
         """Move the page pool onto mesh shardings
         (launch/specs.paged_state_specs); the jitted steps keep the

@@ -1,74 +1,5 @@
-"""``hypothesis`` shim: the real library when installed, otherwise a
-tiny deterministic fallback so the tier-1 suite collects and runs on a
-clean checkout (the container does not ship hypothesis).
+"""One import point for ``hypothesis`` in the test modules."""
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
-The fallback turns ``@given(x=st.floats(0, 1), ...)`` into a loop over a
-fixed number of seeded pseudo-random draws — no shrinking, no database,
-but the same property gets exercised across the same ranges, and runs
-are reproducible.
-"""
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-    HAVE_HYPOTHESIS = True
-except ModuleNotFoundError:
-    HAVE_HYPOTHESIS = False
-
-    import functools
-    import inspect
-    import random
-
-    _DEFAULT_EXAMPLES = 10
-
-    class _Strategy:
-        def __init__(self, draw):
-            self._draw = draw
-
-        def draw(self, rng):
-            return self._draw(rng)
-
-    class st:  # noqa: N801 — mimic `hypothesis.strategies` module name
-        @staticmethod
-        def integers(min_value, max_value):
-            return _Strategy(lambda rng: rng.randint(min_value, max_value))
-
-        @staticmethod
-        def floats(min_value, max_value):
-            return _Strategy(lambda rng: rng.uniform(min_value, max_value))
-
-        @staticmethod
-        def booleans():
-            return _Strategy(lambda rng: rng.random() < 0.5)
-
-        @staticmethod
-        def sampled_from(elements):
-            seq = list(elements)
-            return _Strategy(lambda rng: seq[rng.randrange(len(seq))])
-
-    def given(**strategies):
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                rng = random.Random(0xDA5A)
-                n = getattr(wrapper, "_max_examples", _DEFAULT_EXAMPLES)
-                for _ in range(n):
-                    drawn = {k: s.draw(rng) for k, s in strategies.items()}
-                    fn(*args, **kwargs, **drawn)
-            # Hide the strategy-filled parameters from pytest, which
-            # would otherwise look for fixtures named after them.
-            sig = inspect.signature(fn)
-            kept = [p for name, p in sig.parameters.items()
-                    if name not in strategies]
-            del wrapper.__wrapped__
-            wrapper.__signature__ = sig.replace(parameters=kept)
-            return wrapper
-        return deco
-
-    def settings(max_examples=None, **_ignored):
-        def deco(fn):
-            if max_examples is not None:
-                fn._max_examples = max_examples
-            return fn
-        return deco
-
-__all__ = ["HAVE_HYPOTHESIS", "given", "settings", "st"]
+__all__ = ["given", "settings", "st"]

@@ -58,3 +58,36 @@ def gather_rows(table, idx):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((4096, LANES), jnp.float32),
     )(idx, table)
+
+
+def block_view(bs):
+    """Two returns: the resolver must not guess a branch."""
+    if bs % LANES == 0:
+        return bs // LANES, LANES
+    return 1, bs
+
+
+def head_major_pages(table, q, pages, bs):
+    """The repaired idioms: a (1, 1, rows, hd) block of a head-major
+    pool, scalar blocks (1, 1), and row blocks viewed as full planes."""
+    rows, lanes = block_view(bs)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(4, 8, 2),
+        in_specs=[
+            pl.BlockSpec((1, 1, 16, 64),
+                         lambda b, h, j, t: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, 16, 64),
+                         lambda b, h, j, t: (t[b, j], h, 0, 0)),
+            pl.BlockSpec((1, rows, lanes),
+                         lambda b, h, j, t: (t[b, j], 0, 0)),
+            pl.BlockSpec((1, 1), lambda b, h, j, t: (0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, 1, 16, 64),
+                               lambda b, h, j, t: (b, h, 0, 0)),
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((4, 8, 16, 64), jnp.float32),
+    )(table, q, pages, pages, q)

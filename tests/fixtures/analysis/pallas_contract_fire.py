@@ -1,4 +1,4 @@
-"""pallas-contract positives: arity mismatches and a VMEM blowout.
+"""pallas-contract positives: arities, VMEM, blocks the TPU refuses.
 
 Never imported — the linter fixtures are parsed, not executed.
 """
@@ -70,3 +70,26 @@ def budget_blowout(x):
         out_shape=jax.ShapeDtypeStruct((16384, LANES), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1024, LANES), jnp.float32)],
     )(x)
+
+
+def row_blocks(x_blocks, idx):
+    # FIRE: (1, bs) row blocks over (nb, bs) — the BlockRandK gather
+    row = pl.BlockSpec((1, 128), lambda i, idx_ref: (idx_ref[i], 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(8,), in_specs=[row],
+        out_specs=pl.BlockSpec((8, LANES), lambda i, idx_ref: (i, 0)))
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((64, LANES), jnp.float32),
+    )(idx, x_blocks)
+
+
+def one_kv_head_blocks(pages):
+    return pl.pallas_call(
+        kernel,
+        grid=(4, 8),
+        # FIRE: (1, rows, 1, hd) over an (NP, P, kvH, hd) pool
+        in_specs=[pl.BlockSpec((1, 16, 1, 64), lambda p, h: (p, 0, h, 0))],
+        out_specs=pl.BlockSpec((1, 16, 8, 64), lambda p, h: (p, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((4, 16, 8, 64), jnp.float32),
+    )(pages)

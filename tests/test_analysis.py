@@ -46,7 +46,7 @@ FIXTURE_EXPECTATIONS = [
     ("prng-reuse", "prng_reuse_fire.py", "prng_reuse_clean.py",
      "error", [7, 16, 22]),
     ("pallas-contract", "pallas_contract_fire.py",
-     "pallas_contract_clean.py", "error", [23, 34, 41, 51, 65]),
+     "pallas_contract_clean.py", "error", [23, 34, 41, 51, 65, 77, 92]),
     ("recompile-hazard", "recompile_fire.py", "recompile_clean.py",
      None, [14, 21, 28, 37]),
     ("bit-accounting", "bits_fire.py", "bits_clean.py",

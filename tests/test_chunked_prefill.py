@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import math
 import numpy as np
 import pytest
-from _hypo import given, settings, st   # hypothesis or deterministic fallback
+from _hypo import given, settings, st
 
 from repro.kernels.ops import paged_attention_batched_op, paged_mla_attention_op
 from repro.kernels.paged_attention import (paged_attention_batched_ref,
@@ -240,7 +240,7 @@ def test_ttft_percentiles_reflect_queueing():
 # fused batched kernels vs jnp oracles
 # ----------------------------------------------------------------------
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)   # first example compiles
 @given(seed=st.integers(0, 1000), windowed=st.booleans())
 def test_batched_paged_attention_kernel_matches_ref(seed, windowed):
     """The multi-query GQA launch on random page tables, starts, and
@@ -267,7 +267,7 @@ def test_batched_paged_attention_kernel_matches_ref(seed, windowed):
                                rtol=1e-5, atol=1e-5)
 
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)   # first example compiles
 @given(seed=st.integers(0, 1000), windowed=st.booleans())
 def test_paged_mla_kernel_matches_ref(seed, windowed):
     """The absorbed-form latent kernel: scores against the rank-r pages

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypo import given, settings, st   # hypothesis or deterministic fallback
+from _hypo import given, settings, st
 
 from repro.kernels.ops import paged_attention_op
 from repro.kernels.paged_attention import (paged_attention_ref,
@@ -238,7 +238,7 @@ def test_identical_prompt_shares_all_pages_then_cows_on_decode():
 # paged-attention kernel vs jnp oracle
 # ----------------------------------------------------------------------
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)   # first example compiles
 @given(seed=st.integers(0, 1000), page_size=st.sampled_from([4, 8, 16]),
        windowed=st.booleans())
 def test_paged_attention_kernel_matches_ref(seed, page_size, windowed):

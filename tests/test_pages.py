@@ -1,14 +1,13 @@
 """Page-pool allocator invariants (serving/pages.py, DESIGN.md §11).
 
-Property-tested via tests/_hypo.py (hypothesis when installed, the
-deterministic fallback otherwise): random alloc/retain/release/writable
+Property-tested with hypothesis: random alloc/retain/release/writable
 sequences must conserve pages, keep refcounts consistent, and never
 leave a page simultaneously free and referenced.
 """
 import random
 
 import pytest
-from _hypo import given, settings, st   # hypothesis or deterministic fallback
+from _hypo import given, settings, st
 
 from repro.serving.pages import PagePool, PrefixCache
 

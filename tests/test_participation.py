@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypo import given, settings, st   # hypothesis or deterministic fallback
+from _hypo import given, settings, st
 
 from repro.core.participation import FullParticipation, Independent, SNice
 

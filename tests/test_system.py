@@ -126,3 +126,17 @@ def test_registry_pairs():
     assert sorted(skipped) == [("hubert-xlarge", "decode_32k"),
                                ("hubert-xlarge", "long_500k")]
     assert len(statuses) == 40
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The persistent compile cache uses $JAX_COMPILATION_CACHE_DIR when
+    set, else one fixed, gitignored directory inside the checkout."""
+    from repro.launch import compile_cache
+
+    monkeypatch.setenv(compile_cache.ENV, "/elsewhere/cache")
+    assert compile_cache.cache_dir() == "/elsewhere/cache"
+    monkeypatch.delenv(compile_cache.ENV)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.cache_dir() == os.path.join(root, ".jax_cache")
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

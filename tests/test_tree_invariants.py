@@ -1,6 +1,4 @@
-"""Property tests for the hierarchical fleet (fl/tree.py), via the
-hypothesis shim in tests/_hypo.py (real hypothesis when installed, a
-seeded deterministic fallback otherwise):
+"""Property tests (hypothesis) for the hierarchical fleet (fl/tree.py):
 
 (a) the wire-bits ledger balances — the cumulative ``bits_cum`` metric
     equals the sum of per-hop totals, which themselves equal the
