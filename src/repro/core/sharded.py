@@ -53,6 +53,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import compat
 from repro.core import participation, variants
+from repro.obs.trace import phase_scope
 # Re-exported: the BlockRandK wire helpers moved to the rule layer
 # (core/variants.py); existing imports from this module keep working.
 from repro.core.variants import (block_plan, block_randk_dense,
@@ -357,6 +358,7 @@ class ShardedDasha:
         )(jnp.arange(self.n_nodes))
 
     # -- node + aggregation ------------------------------------------------
+    @phase_scope("dasha_dispatch")
     def dispatch(self, grads_new: PyTree, grads_old: PyTree,
                  state: ShardedDashaState, key: Array, *,
                  mini_new: Optional[PyTree] = None,
@@ -636,6 +638,7 @@ class ShardedDasha:
                                        bits_sent=bits)
 
     # -- the server-side apply ---------------------------------------------
+    @phase_scope("dasha_commit")
     def commit(self, state: ShardedDashaState, disp: ShardedDispatch,
                weight=1.0) -> ShardedDashaState:
         """Lines 12/19 of Algorithm 1 for one dispatched round: apply a

@@ -5,8 +5,7 @@ Three pillars, all stdlib-only (jax imported lazily where needed):
 - ``obs.trace``    — nestable spans, dual wall/virtual clocks, Chrome
   trace-event export (loadable in Perfetto).
 - ``obs.metrics``  — typed counter/gauge/histogram registry, jsonl
-  sink, Prometheus text exposition; the engines publish their ledgers
-  into it.
+  sink and JSON snapshot; the engines publish their ledgers into it.
 - ``obs.monitors`` — live invariant checks (wire-bits reconciliation,
   pool refcount conservation, staleness-hop monotonicity) firing as
   structured warnings in traced runs.
@@ -16,6 +15,7 @@ benches use to honor ``--trace-out`` / ``--metrics-out``.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Dict, Optional
 
 from repro.obs import metrics, monitors, provenance, trace
@@ -23,13 +23,15 @@ from repro.obs.metrics import (Counter, Gauge, Histogram, JsonlSink,
                                Registry, get_registry)
 from repro.obs.monitors import MonitorResult, ObsWarning
 from repro.obs.trace import (Tracer, active, counter, instant,
-                             kernel_scope, set_virtual_time, span, traced)
+                             kernel_scope, phase_scope, set_virtual_time,
+                             span, traced)
 
 __all__ = [
     "metrics", "monitors", "provenance", "trace",
     "Counter", "Gauge", "Histogram", "JsonlSink", "Registry",
     "get_registry", "MonitorResult", "ObsWarning", "Tracer", "active",
-    "counter", "instant", "kernel_scope", "set_virtual_time", "span",
+    "counter", "instant", "kernel_scope", "phase_scope",
+    "set_virtual_time", "span",
     "traced", "ObsRun", "start_run", "add_cli_flags", "profiler_trace",
 ]
 
@@ -98,18 +100,25 @@ def add_cli_flags(ap) -> None:
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
                     help="capture the hot section with jax.profiler "
                          "(TensorBoard/Perfetto-loadable; the "
-                         "repro.kernel.* named scopes appear in the "
-                         "device trace)")
+                         "repro.kernel.* and repro.phase.* named scopes "
+                         "appear in the device trace, the program's "
+                         "spans in its host plane)")
 
 
+@contextlib.contextmanager
 def profiler_trace(profile_dir: Optional[str]):
     """``jax.profiler.trace(profile_dir)`` as a context manager, or a
-    no-op context when ``profile_dir`` is None (or jax is absent — the
-    obs core stays stdlib-only).  The launch CLIs wrap their hot
-    section in this so ``--profile-dir`` captures the 14
-    ``kernel_scope`` names in a real device profile alongside our
-    spans."""
+    no-op context when ``profile_dir`` is None (jax is then never
+    imported — the obs core stays stdlib-only).  The launch CLIs wrap
+    their hot section in this so ``--profile-dir`` captures the
+    ``kernel_scope`` and ``phase_scope`` names in a real device
+    profile; while it is open every :func:`span` is also a profiler
+    ``TraceAnnotation`` (:func:`trace.profiler_spans`), so the
+    program's spans sit in the profile's host plane on the device
+    trace's clock."""
     if not profile_dir:
-        return trace._NULL_SPAN
+        yield
+        return
     import jax
-    return jax.profiler.trace(profile_dir)
+    with jax.profiler.trace(profile_dir), trace.profiler_spans():
+        yield

@@ -1,4 +1,4 @@
-"""Typed metrics registry + jsonl sink + Prometheus text exposition.
+"""Typed metrics registry + jsonl sink + JSON snapshot.
 
 Naming scheme (DESIGN.md §13): dotted, ``<subsystem>.<metric>[.<tag>]``
 
@@ -17,14 +17,12 @@ All metric types are float-valued.  Counters only accumulate
 observations and expose count/sum/min/max/percentiles.  The registry
 is get-or-create by name with a kind check, so publishing sites never
 coordinate.  ``snapshot()``/``write_snapshot()`` produce the JSON
-artifact validated by obs/validate.py; ``to_prometheus()`` renders the
-text exposition format.
+artifact validated by obs/validate.py.
 """
 from __future__ import annotations
 
 import json
 import os
-import re
 import time
 from typing import Any, Dict, IO, List, Mapping, Optional
 
@@ -164,26 +162,6 @@ class Registry:
         with open(path, "w") as f:
             json.dump(self.snapshot(extra), f, indent=1)
         return path
-
-    def to_prometheus(self) -> str:
-        """Prometheus text exposition (dots -> underscores)."""
-        lines: List[str] = []
-        for name in self.names():
-            m = self._metrics[name]
-            pname = "repro_" + re.sub(r"[^a-zA-Z0-9_]", "_", name)
-            if m.kind == "histogram":
-                lines.append(f"# TYPE {pname} summary")
-                lines.append(f"{pname}_count {m.count}")
-                lines.append(f"{pname}_sum {m.sum}")
-                for q in (50, 95):
-                    p = m.percentile(q)
-                    if p is not None:
-                        lines.append(
-                            f'{pname}{{quantile="0.{q}"}} {p}')
-            else:
-                lines.append(f"# TYPE {pname} {m.kind}")
-                lines.append(f"{pname} {m.value}")
-        return "\n".join(lines) + "\n"
 
 
 _registry = Registry()

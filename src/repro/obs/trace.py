@@ -20,7 +20,8 @@ Design (DESIGN.md §13):
   (client dispatch → edge flush → root commit) so a committed round can
   be walked back to the exact client/hop chain that bounded it — the
   input the critical-path engine in ``obs/analyze`` consumes.
-- **Disabled fast path.**  With no tracer installed the module-level
+- **Disabled fast path.**  With no tracer installed (and, for
+  :func:`span`, no profile open) the module-level
   helpers return a shared no-op span / return immediately — no
   allocation, no branching beyond one global load — so instrumentation
   can stay unconditional on hot paths (benchmarks/bench_obs.py asserts
@@ -37,16 +38,21 @@ Design (DESIGN.md §13):
   loadable in Perfetto (https://ui.perfetto.dev) or
   ``chrome://tracing``.
 
-The module is stdlib-only.  :func:`kernel_scope` lazily imports jax to
-wrap Pallas kernel launch sites in ``jax.named_scope`` so kernels show
-up named in ``jax.profiler`` device traces; it degrades to a no-op
-when jax is absent.
+The module is stdlib-only.  :func:`kernel_scope` and :func:`phase_scope`
+lazily import jax to wrap Pallas kernel launch sites and the phases of
+the DASHA-PP step in ``jax.named_scope`` so they show up named in
+``jax.profiler`` device traces; both degrade to a no-op when jax is
+absent.  While :func:`profiler_spans` is open (``obs.profiler_trace``
+opens it around a profile), every :func:`span` also enters
+``jax.profiler.TraceAnnotation(name)``, so the program's spans land in
+the profile's host plane on the device trace's clock.
 
 Event appends are plain list appends (atomic under CPython); the
 runtimes instrumented here are single-threaded per process.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import time
@@ -56,7 +62,7 @@ __all__ = [
     "Tracer", "configure", "install", "uninstall", "get_tracer",
     "active", "span", "instant", "counter", "set_virtual_time",
     "clear_virtual_time", "flow_start", "flow_step", "flow_end",
-    "traced", "kernel_scope", "export",
+    "traced", "kernel_scope", "phase_scope", "profiler_spans", "export",
 ]
 
 WALL_PID = 1      # wall-clock process in the exported trace
@@ -281,17 +287,67 @@ class Tracer:
 # module-level API (the instrumented code uses only these)
 # ---------------------------------------------------------------------
 _tracer: Optional[Tracer] = None
+# jax.profiler.TraceAnnotation while profiler_spans() is open, else None
+_annotation: Optional[Any] = None
+# what span() opens: None (disabled), or a function of
+# (name, cat, track, args) -- kept in one global so the disabled path
+# stays one load
+_open: Optional[Any] = None
+
+
+class _AnnotatedSpan:
+    """A span that also enters a profiler ``TraceAnnotation``."""
+    __slots__ = ("_ann", "_inner")
+
+    def __init__(self, ann, inner):
+        self._ann = ann
+        self._inner = inner
+
+    def set(self, **args):
+        self._inner.set(**args)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._inner.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._inner.__exit__(*exc)
+        self._ann.__exit__(*exc)
+        return False
+
+
+def _open_traced(name, cat, track, args):
+    return _tracer.span(name, cat, track, **args)
+
+
+def _open_annotated(name, cat, track, args):
+    t = _tracer
+    inner = _NULL_SPAN if t is None else t.span(name, cat, track, **args)
+    return _AnnotatedSpan(_annotation(name), inner)
+
+
+def _refresh() -> None:
+    global _open
+    if _annotation is not None:
+        _open = _open_annotated
+    elif _tracer is not None:
+        _open = _open_traced
+    else:
+        _open = None
 
 
 def install(tracer: Tracer) -> Tracer:
     global _tracer
     _tracer = tracer
+    _refresh()
     return tracer
 
 
 def uninstall() -> Optional[Tracer]:
     global _tracer
     t, _tracer = _tracer, None
+    _refresh()
     return t
 
 
@@ -310,11 +366,13 @@ def active() -> bool:
 
 
 def span(name: str, cat: str = "", track: Optional[str] = None, **args):
-    """Open a span on the installed tracer (no-op span when disabled)."""
-    t = _tracer
-    if t is None:
+    """Open a span on the installed tracer and, while
+    :func:`profiler_spans` is open, as a profiler ``TraceAnnotation``
+    (no-op span when neither is on)."""
+    open_ = _open
+    if open_ is None:
         return _NULL_SPAN
-    return t.span(name, cat, track, **args)
+    return open_(name, cat, track, args)
 
 
 def instant(name: str, track: Optional[str] = None, **args) -> None:
@@ -393,6 +451,44 @@ def kernel_scope(name: str):
     except Exception:      # pragma: no cover - jax is present in CI
         return _NULL_SPAN
     return jax.named_scope(f"repro.kernel.{name}")
+
+
+def phase_scope(name: str):
+    """Annotate a phase of the DASHA-PP step (server step, gradient
+    pair, dispatch, commit).
+
+    Returns ``jax.named_scope("repro.phase.<name>")``: every op traced
+    inside carries the name in its ``tf_op`` metadata, so a device
+    trace splits the step's time by phase.  Kernel scopes nest inside
+    phases; phases do not nest in each other.  Like
+    :func:`kernel_scope` it is metadata only and degrades to a no-op
+    context when jax is unavailable.
+    """
+    try:
+        import jax
+    except Exception:      # pragma: no cover - jax is present in CI
+        return _NULL_SPAN
+    return jax.named_scope(f"repro.phase.{name}")
+
+
+@contextlib.contextmanager
+def profiler_spans():
+    """While open, every :func:`span` also enters
+    ``jax.profiler.TraceAnnotation(name)`` for its duration, with or
+    without an installed tracer, so the spans appear in an open
+    profile's host plane on the device trace's clock.  Open it only
+    while a profile is being recorded (``obs.profiler_trace`` does)."""
+    global _annotation
+    import jax
+
+    prev = _annotation
+    _annotation = jax.profiler.TraceAnnotation
+    _refresh()
+    try:
+        yield
+    finally:
+        _annotation = prev
+        _refresh()
 
 
 def export(path: str) -> Optional[str]:

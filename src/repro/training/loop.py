@@ -55,7 +55,8 @@ def train(trainer: Trainer, state: TrainState,
         gstep = start + i
         placed = place_batch(batch, mesh, data_axes)
         key = round_train_key(seed, gstep)
-        with obs_trace.span("train.step", track="train", step=gstep):
+        # times the step's (asynchronous) dispatch, not its device work
+        with obs_trace.span("train.dispatch", track="train", step=gstep):
             state, metrics = step_fn(state, placed, key)
         bits_seen.append(metrics.bits_sent)
         parts_seen.append(metrics.participants)

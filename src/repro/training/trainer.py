@@ -47,6 +47,7 @@ from repro.core.sharded import (ShardedDasha, ShardedDashaConfig,
 from repro.data.sharding import batch_specs
 from repro.models.common import param_specs_like
 from repro.models.model import Model
+from repro.obs.trace import phase_scope
 from repro.training.optim import ServerOptimizer
 
 Array = jax.Array
@@ -198,16 +199,27 @@ class Trainer:
         with g^t plus the variant's per-node gradient oracles — shared
         verbatim between the sync :meth:`train_step` and the async
         :meth:`dispatch_step` (DESIGN.md §10)."""
-        model, eng, cfg = self.model, self.engine, self.cfg
+        params_new, opt_new = self._server_step(state)
+        return (params_new, opt_new) + self._grad_pair(state, batch, key,
+                                                       params_new)
 
-        # (1) server step with g^t
-        delta, opt_new = cfg.server.update(state.dasha.g, state.opt,
-                                           state.params)
+    @phase_scope("server_step")
+    def _server_step(self, state: TrainState):
+        """(1) server step with g^t, cast back to the params' dtype."""
+        delta, opt_new = self.cfg.server.update(state.dasha.g, state.opt,
+                                                state.params)
         params_new = jax.tree.map(
             lambda p, d: (p.astype(jnp.float32) + d).astype(p.dtype),
             state.params, delta)
+        return params_new, opt_new
 
-        # (2) the variant's per-node gradient oracles
+    @phase_scope("grad_pair")
+    def _grad_pair(self, state: TrainState, batch: PyTree, key: Array,
+                   params_new: PyTree):
+        """(2) the variant's per-node gradient oracles at x^{t+1}
+        (``params_new``) and x^t."""
+        model, eng, cfg = self.model, self.engine, self.cfg
+
         def node_loss(p, node_batch):
             return model.loss(p, node_batch)
 
@@ -289,8 +301,7 @@ class Trainer:
             losses_old, g_old = per_node_value_and_grads(
                 node_loss, state.params, batch)
 
-        return (params_new, opt_new, cache_new, losses_new, losses_old,
-                g_new, g_old, node_kwargs)
+        return cache_new, losses_new, losses_old, g_new, g_old, node_kwargs
 
     def train_step(self, state: TrainState, batch: PyTree, key: Array
                    ) -> Tuple[TrainState, TrainMetrics]:

@@ -1,6 +1,6 @@
 """The PR 8 observability layer (repro/obs/, DESIGN.md §13): span
 tracing with dual clocks and Chrome export, the typed metrics registry
-+ jsonl sink + Prometheus exposition, the MetricsLogger shim, live
++ jsonl sink + JSON snapshot, the MetricsLogger shim, live
 invariant monitors, artifact validation, and the traced smokes whose
 ``fleet.tier_bits`` / ``train.bits_sent`` totals must reconcile
 exactly with the engines' own ledgers."""
@@ -142,6 +142,108 @@ def test_kernel_scope_is_jit_compatible():
     assert float(f(jnp.float32(3.0))) == 6.0
 
 
+PHASES = ("server_step", "grad_pair", "dasha_dispatch", "dasha_commit")
+
+
+def _tiny_trainer(use_pallas: bool):
+    from repro.compat import make_mesh
+    from repro.core.sharded import ShardedDashaConfig
+    from repro.models import Model, get_smoke_config
+    from repro.training.optim import paper_server
+    from repro.training.trainer import Trainer, TrainerConfig
+
+    mesh = make_mesh((1, 1), ("data", "model"))
+    model = Model(get_smoke_config("granite-3-2b").with_overrides(
+        vocab_size=64))
+    dcfg = ShardedDashaConfig(
+        gamma=1e-3, a=0.02, b=0.9, p_a=0.5, sampler="independent",
+        compression_ratio=0.25, block_size=128, data_axes=("data",),
+        variant="mvr", use_pallas=use_pallas)
+    return Trainer(model, mesh, TrainerConfig(dasha=dcfg,
+                                              server=paper_server(1e-3)))
+
+
+@pytest.mark.parametrize("use_pallas", [True, False])
+def test_phase_scopes_name_the_compiled_step(use_pallas):
+    """Every phase of the DASHA-PP step names its ops in the compiled
+    program's metadata, and the update kernels' scopes sit inside the
+    dispatch phase."""
+    import re
+
+    from repro.compat import use_mesh
+
+    tr = _tiny_trainer(use_pallas)
+    batch = {"tokens": jnp.zeros((1, 1, 16), jnp.int32)}
+    with use_mesh(tr.mesh):
+        state = jax.eval_shape(tr.init, jax.random.key(0))
+        compiled = tr.jit_train_step(batch).lower(
+            state, batch, jax.random.key(1)).compile()
+    names = re.findall(r'op_name="([^"]*)"', compiled.as_text())
+    for phase in PHASES:
+        assert any(f"repro.phase.{phase}/" in n for n in names), phase
+    # phases do not nest in each other
+    assert all(len(re.findall(r"repro\.phase\.", n)) <= 1 for n in names)
+    kernels = [n for n in names
+               if re.search(r"repro\.kernel\.(dasha_|block_)", n)]
+    assert bool(kernels) == use_pallas
+    assert all("repro.phase.dasha_dispatch/" in n for n in kernels)
+
+
+def test_spans_are_profiler_annotations_while_a_profile_is_open(tmp_path):
+    """Under ``obs.profiler_trace`` the program's spans are host events
+    of the ``.xplane.pb``, with no tracer installed; once the profile
+    closes, span() is the shared null span again."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    from repro.compat import use_mesh
+    from repro.obs import profiler_trace
+    from repro.training.loop import train
+    from repro.training.metrics import MetricsLogger
+
+    obs_trace.uninstall()
+    tr = _tiny_trainer(use_pallas=False)
+    toks = jnp.tile(jnp.arange(16) % 7, (1, 1, 1)).astype(jnp.int32)
+
+    def fixed():
+        while True:
+            yield {"tokens": toks}
+
+    state = tr.init(jax.random.key(0))
+    logger = MetricsLogger(print_every=1000)
+    with use_mesh(tr.mesh), profiler_trace(str(tmp_path)):
+        assert obs_trace.span("x") is not obs_trace._NULL_SPAN
+        state = train(tr, state, fixed(), num_steps=2, log_every=1000,
+                      logger=logger)
+        jax.block_until_ready(state)
+    logger.close()
+    assert obs_trace.span("x") is obs_trace._NULL_SPAN
+    assert obs_trace.get_tracer() is None
+
+    files = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(files) == 1
+    pd = ProfileData.from_file(files[0])
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", DeprecationWarning)
+        host = [e.name for plane in pd.planes
+                if plane.name.startswith("/host:")
+                for line in plane.lines for e in line.events]
+    assert host.count("train.dispatch") == 2
+
+
+def test_profiled_spans_still_reach_an_installed_tracer(tracer, tmp_path):
+    from repro.obs import profiler_trace
+
+    with profiler_trace(str(tmp_path)):
+        with obs_trace.span("outer", track="t") as sp:
+            sp.set(k=1)
+    assert obs_trace.span("y") is not obs_trace._NULL_SPAN  # tracer on
+    ev = [e for e in tracer.events if e["name"] == "outer"]
+    assert len(ev) == 1 and ev[0]["args"] == {"k": 1}
+
+
 # ----------------------------------------------------------------------
 # metrics: registry, sink, exposition
 # ----------------------------------------------------------------------
@@ -170,7 +272,7 @@ def test_registry_types_and_kind_mismatch(registry):
         registry.histogram("a.level")
 
 
-def test_snapshot_validates_and_prometheus_exposition(registry, tmp_path):
+def test_snapshot_validates(registry, tmp_path):
     registry.counter("train.steps").inc(6)
     registry.gauge("fleet.tier_bits").set(128.0)
     registry.histogram("fleet.staleness").observe(1.0, n=4)
@@ -181,11 +283,6 @@ def test_snapshot_validates_and_prometheus_exposition(registry, tmp_path):
     assert obs_validate.validate_metrics(doc) == []
     assert doc["provenance"] == {"x": 1}
     assert doc["metrics"]["fleet.tier_bits"]["value"] == 128.0
-
-    text = registry.to_prometheus()
-    assert "# TYPE repro_train_steps counter" in text
-    assert "repro_fleet_tier_bits 128.0" in text
-    assert "repro_fleet_staleness_count 4" in text
 
 
 def test_jsonl_sink_roundtrip_and_idempotent_close(tmp_path):
@@ -477,7 +574,7 @@ def test_traced_train_smoke_reconciles_bits_ledger():
         kind, errors = validate_file(tpath)
         assert (kind, errors) == ('trace', []), errors
         assert sum(1 for e in tracer.events
-                   if e['name'] == 'train.step') == 4
+                   if e['name'] == 'train.dispatch') == 4
         with open(os.path.join(out, 'train.jsonl')) as f:
             recs = [json.loads(line) for line in f]
         assert len(recs) == 4
