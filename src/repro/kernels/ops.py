@@ -22,6 +22,7 @@ from repro.kernels.dasha_update import (buffered_commit_pallas,
                                         dasha_tail_batched_pallas,
                                         dasha_update_batched_pallas,
                                         dasha_update_pallas)
+from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.paged_attention import (paged_attention_batched_pallas,
                                            paged_attention_pallas,
                                            paged_mla_attention_pallas)
@@ -183,6 +184,17 @@ def buffered_commit_op(g: Array, m_buf: Array, weights: Array, *,
     return buffered_commit_pallas(
         *_f32(g, m_buf, weights), inv_n=1.0 / float(n_nodes),
         interpret=interp)
+
+
+@_scoped("flash_attention")
+def flash_attention_op(q: Array, k: Array, v: Array, *,
+                       interpret: bool | None = None) -> Array:
+    """Causal GQA self-attention on the flash kernel
+    (kernels/flash_attention.py): q (B, T, H, hd), k/v (B, T, kvH, hd),
+    positions ``arange(T)``.  Returns (B, T, H, hd) in v's dtype; the
+    operands keep their dtype, accumulation is f32."""
+    interp = _interpret_default() if interpret is None else interpret
+    return flash_attention_pallas(q, k, v, interpret=interp)
 
 
 @_scoped("paged_attention")

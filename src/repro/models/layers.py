@@ -101,6 +101,12 @@ def blockwise_gqa_attention(q: Array, k: Array, v: Array,
     (Tq, Tk) score matrix is never materialized (a 32k prefill otherwise
     needs O(T^2) temp — observed 0.5 TB/device in the dry-run).
 
+    The fallback of :func:`self_attention` past 1024 tokens: windowed,
+    prefix-LM and bidirectional masks, programs over a mesh of several
+    devices, and every run off the TPU.  One TPU chip's plain causal
+    path is the flash kernel (kernels/flash_attention.py).  MLA calls
+    it directly.
+
     q: (B, Tq, H, hd); k/v: (B, Tk, kvH, hd).  Positions drive the
     causal/window/prefix mask exactly like
     :func:`attention_weights_mask`.
@@ -228,6 +234,35 @@ def gqa_attention(q: Array, k: Array, v: Array, mask: Array) -> Array:
     return out.reshape(B, Tq, H, hd)
 
 
+def self_attention(q: Array, k: Array, v: Array, positions: Array, *,
+                   causal: bool, window: Optional[int],
+                   full_prefix: int = 0) -> Array:
+    """Training/prefill self-attention with no cache: q (B, T, H, hd),
+    k/v (B, T, kvH, hd), ``positions`` (T,) of both queries and keys.
+
+    Past 1024 tokens the (T, T) scores are never materialized.  On one
+    TPU chip a plain causal mask runs on the flash kernel
+    (:func:`repro.kernels.ops.flash_attention_op`); windowed, prefix-LM
+    and bidirectional masks, programs traced over a mesh of several
+    devices (a Mosaic kernel is not partitioned outside a shard_map),
+    and every run off the TPU take :func:`blockwise_gqa_attention`."""
+    from repro.kernels.ops import flash_attention_op, interpret_default
+    T = q.shape[1]
+    if T <= 1024:
+        mask = attention_weights_mask(positions, positions, causal, window,
+                                      full_prefix=full_prefix)
+        return gqa_attention(q, k, v, mask)
+    one_device = jax.typeof(q).sharding.mesh.size <= 1
+    if (causal and window is None and not full_prefix and one_device
+            and not interpret_default()):
+        # the kernel assumes positions == arange(T), which holds here:
+        # Model._embed builds them so for every cache-free forward
+        return flash_attention_op(q, k, v)
+    return blockwise_gqa_attention(q, k, v, positions, positions,
+                                   causal=causal, window=window,
+                                   full_prefix=full_prefix)
+
+
 def init_attention(key: Array, cfg) -> dict:
     hd = cfg.hd
     ks = jax.random.split(key, 5)
@@ -334,17 +369,9 @@ def attention_block(p: dict, x: Array, positions: Array, cfg,
         return out @ p["wo"], KVCache(k=k_new, v=v_new)
     if cache is None:
         k_pos = positions[0] if positions.ndim > 1 else positions
-        q_pos = k_pos
-        if T > 1024:
-            # flash-style blockwise path: O(block^2) memory
-            out = blockwise_gqa_attention(
-                q, k, v, q_pos, k_pos, causal=causal,
-                window=cfg.attention_window, full_prefix=full_prefix)
-        else:
-            mask = attention_weights_mask(q_pos, k_pos, causal,
-                                          cfg.attention_window,
-                                          full_prefix=full_prefix)
-            out = gqa_attention(q, k, v, mask)
+        out = self_attention(q, k, v, k_pos, causal=causal,
+                             window=cfg.attention_window,
+                             full_prefix=full_prefix)
         new_cache = KVCache(k=k, v=v)
     elif jnp.ndim(cache_pos) == 0:
         S = cache.k.shape[1]

@@ -8,8 +8,10 @@ import numpy as np
 import pytest
 
 from repro.models import ARCH_IDS, Model, count_params, get_smoke_config
+from repro.kernels.ops import flash_attention_op
 from repro.models.layers import (attention_weights_mask,
-                                 blockwise_gqa_attention, gqa_attention)
+                                 blockwise_gqa_attention, gqa_attention,
+                                 self_attention)
 
 B, T = 2, 16
 
@@ -121,6 +123,88 @@ def test_blockwise_attention_matches_dense():
                                       q_block=48, k_block=64)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-4, atol=2e-5)
+
+
+# The flash kernel keeps bf16 operands: probabilities and outputs carry
+# 8 significant bits, so an O(1) output is off by a few 2^-8 of the
+# largest |v| (~4 here) and a gradient by about 2^-8 of its norm.  f32
+# operands leave only the kernel's summation order.
+FLASH_TOL = {jnp.bfloat16: dict(out=3e-2, grad=1e-2),
+             jnp.float32: dict(out=1e-4, grad=1e-4)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_flash_attention_matches_dense(dtype):
+    """The flash kernel in interpret mode against the dense f32 causal
+    attention, forward and the gradients of q, k and v.  T 200 is no
+    multiple of the block, so the end padding is exercised."""
+    key = jax.random.key(0)
+    Bq, Tq, H, kvH, hd = 2, 200, 8, 2, 64
+    q, k, v, ct = (jax.random.normal(jax.random.fold_in(key, i), shape)
+                   for i, shape in enumerate([(Bq, Tq, H, hd),
+                                              (Bq, Tq, kvH, hd),
+                                              (Bq, Tq, kvH, hd),
+                                              (Bq, Tq, H, hd)]))
+    q, k, v = (x.astype(dtype) for x in (q, k, v))
+    pos = jnp.arange(Tq)
+    mask = attention_weights_mask(pos, pos, True, None)
+
+    def dense(q, k, v):
+        f32 = jnp.float32
+        return gqa_attention(q.astype(f32), k.astype(f32), v.astype(f32),
+                             mask)
+
+    def flash(q, k, v):
+        return flash_attention_op(q, k, v, interpret=True)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * ct)
+
+    tol = FLASH_TOL[dtype]
+    out = jax.jit(flash)(q, k, v)
+    assert out.shape == q.shape and out.dtype == dtype
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(dense(q, k, v)), rtol=0,
+                               atol=tol["out"])
+    grads = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+    refs = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    for g, r in zip(grads, refs):
+        assert g.dtype == dtype
+        err = jnp.linalg.norm(g.astype(jnp.float32) - r) / jnp.linalg.norm(r)
+        assert float(err) < tol["grad"]
+
+
+@pytest.mark.parametrize(
+    "causal, window, prefix, on_tpu, devices, T, path",
+    [(True, None, 0, True, 1, 1100, "flash"),
+     (True, 64, 0, True, 1, 1100, "scan"),
+     (True, None, 5, True, 1, 1100, "scan"),
+     (False, None, 0, True, 1, 1100, "scan"),
+     (True, None, 0, False, 1, 1100, "scan"),
+     (True, None, 0, True, 4, 1100, "scan"),
+     (True, None, 0, True, 1, 1024, "dense")])
+def test_self_attention_routing(monkeypatch, causal, window, prefix,
+                                on_tpu, devices, T, path):
+    """Past 1024 tokens only a plain causal mask on one TPU chip takes
+    the flash kernel; windowed, prefix-LM, bidirectional, off-TPU runs
+    and programs over a mesh of several devices take the blockwise scan.
+    Traced only, never run: the TPU is stood in for by compiled (not
+    interpreted) kernels, the mesh by an abstract one."""
+    from jax.sharding import AbstractMesh, NamedSharding, PartitionSpec
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0" if on_tpu else "1")
+    on = NamedSharding(AbstractMesh((devices, 1), ("data", "model")),
+                       PartitionSpec())
+    q = jax.ShapeDtypeStruct((1, T, 4, 64), jnp.bfloat16, sharding=on)
+    kv = jax.ShapeDtypeStruct((1, T, 2, 64), jnp.bfloat16, sharding=on)
+    jaxpr = str(jax.make_jaxpr(
+        lambda q, k, v: self_attention(q, k, v, jnp.arange(T),
+                                       causal=causal, window=window,
+                                       full_prefix=prefix))(q, kv, kv))
+    taken = ("flash" if "pallas_call" in jaxpr else
+             "scan" if "scan" in jaxpr else "dense")
+    assert taken == path
 
 
 def test_moe_capacity_drops_bounded():

@@ -102,3 +102,29 @@ def test_kernel_compiles_for_v5e(one_chip, name):
             for s, dt in shapes]
     compiled = _compile(fn, *args)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_training_attention_compiles_to_flash_kernels(one_chip, monkeypatch):
+    """The routed self-attention at train-d8-seq4k's shapes (granite-3-2b:
+    T 4095, 32 query / 8 KV heads of 64, bf16), differentiated through
+    ``jax.checkpoint`` as the model's layers are, compiles to the flash
+    kernel's forward and its fused backward (dq, dk and dv in the dkv
+    launch) and to no f32 score scan."""
+    from repro.models.layers import self_attention
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    B, T, H, kvh, hd = 1, 4095, 32, 8, 64
+
+    def loss(q, k, v):
+        out = self_attention(q, k, v, jnp.arange(T), causal=True,
+                             window=None)
+        return jnp.sum(out.astype(F32))
+
+    grad = jax.grad(jax.checkpoint(loss), argnums=(0, 1, 2))
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+            for s in [(B, T, H, hd), (B, T, kvh, hd), (B, T, kvh, hd)]]
+    text = _compile(grad, *args).as_text()
+    assert text.count("tpu_custom_call") >= 2
+    for phase in ("fwd", "dkv"):
+        assert f"splash_mqa_{phase}" in text
+    assert " while(" not in text
