@@ -3,13 +3,17 @@
 Set-up makes the weights from the seed, builds the engine as the
 configuration states it, runs one fused pass of every shape the cell's
 traffic can ask for (each chunk width against each page-table width its
-lengths reach), and then serves the traffic's first ``lead_s`` seconds,
-so that the window opens on an engine at its steady load.  The window
+lengths reach) and one copy-on-write of a page, and then serves the
+traffic's first ``lead_s`` seconds, so that the window opens on an
+engine at its steady load.  The window
 offers the traffic file's requests at their due times, between passes,
 and serves them greedily; after it closes, the engine drains what was
-due in it until the file's drain limit.  Times are on the host clock: a
-request's first token and last token are stamped when the pass that
-produced them returns.
+due in it until the file's drain limit.  Times are on the host clock:
+each served token is stamped when the pass that produced it returns.
+Latency is read over every request due in the window: time to first
+token from when the request was due, at its median and at the highest
+percentile with ten requests beyond it, and the gaps between the
+tokens of those requests, all pooled.
 """
 from __future__ import annotations
 
@@ -17,7 +21,7 @@ import functools
 import gc
 import time
 from collections import deque
-from typing import Dict, Optional
+from typing import Dict, List, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -57,6 +61,49 @@ def percentile(values, q: float) -> float:
     return float(np.percentile(np.asarray(values, np.float64), q))
 
 
+TAIL_PERCENTILES = (99, 95, 90)
+TPOT_PERCENTILE = 95
+
+
+def tail_percentile(n: int) -> Optional[int]:
+    """The highest of :data:`TAIL_PERCENTILES` with at least ten of
+    ``n`` samples beyond it; None where none has."""
+    for q in TAIL_PERCENTILES:
+        if n * (100 - q) >= 10 * 100:
+            return q
+    return None
+
+
+def latency(due: Dict[int, float], stamps: Dict[int, List[float]],
+            finished: Set[int], gave_up: float
+            ) -> Tuple[Dict[str, float], Dict[str, int]]:
+    """End-to-end latency over the requests ``due`` (uid -> due time).
+
+    ``stamps[uid]`` are the times at which the request's served tokens
+    were produced, the first token first.  A request with no first token
+    reads ``gave_up`` as its first token's time.  Time to first token is
+    read at its median and at :func:`tail_percentile` of the requests
+    due, named by that percentile (``serve_ttft_p90_s``); the gaps
+    between successive tokens of every request due are pooled, and read
+    at their median and ``serve_tpot_p95_s``.  A request that did not
+    finish, refused ones among them, counts in ``failed``.  Returns the
+    metrics and the sample counts."""
+    ttft = [(stamps[u][0] if stamps.get(u) else gave_up) - d
+            for u, d in due.items()]
+    gaps = [b - a for u in due for a, b in zip(stamps.get(u, ()),
+                                                stamps.get(u, ())[1:])]
+    e2e = {"serve_ttft_p50_s": percentile(ttft, 50) if ttft else gave_up}
+    q = tail_percentile(len(ttft))
+    if q is not None:
+        e2e[f"serve_ttft_p{q}_s"] = percentile(ttft, q)
+    e2e["serve_tpot_p50_s"] = percentile(gaps, 50) if gaps else gave_up
+    e2e[f"serve_tpot_p{TPOT_PERCENTILE}_s"] = \
+        percentile(gaps, TPOT_PERCENTILE) if gaps else gave_up
+    n = {"requests": len(ttft), "gaps": len(gaps),
+         "failed": sum(1 for u in due if u not in finished)}
+    return e2e, n
+
+
 def run(cell, seconds: float, seed: int, devices, t_start: float,
         trace_dir: Optional[str] = None) -> Dict:
     from repro.models import Model
@@ -93,21 +140,26 @@ def run(cell, seconds: float, seed: int, devices, t_start: float,
                 jnp.zeros((B,), jnp.int32))
             eng._caches = st.caches
             np.asarray(jnp.argmax(logits, axis=-1))
+    # the copy-on-write of a page: the prefix cache shares a prompt's
+    # first tokens whenever two prompts begin alike, which random prompts
+    # sometimes do (page 0 onto itself: nothing changes)
+    eng._caches = eng._copy_fn(eng._caches, 0, 0)
+    jax.block_until_ready(eng._caches)
     reqs = gen.requests(tr, seconds, seed, m["vocab_size"])
     lead = float(tr.get("lead_s", 0.0))
 
     pending = deque(reqs)
     live: Dict[int, Request] = {}
-    first: Dict[int, float] = {}
+    stamps: Dict[int, List[float]] = {}  # each served token's time
     last: Dict[int, float] = {}
     refused = set()
-    late = 0.0
+    late = []                          # how late each request was offered
     passes = []                        # (start, end, starts, q_lens)
     waiting = []                       # (time, requests queued or live)
     window_tokens = 0
     backlog = (0, 0)
     drain_end = seconds + float(tr["drain_s"])
-    window = jax.profiler.TraceAnnotation(trace_reduce.WINDOW_SPAN)
+    window = None                      # the window's span, once open
     phase = 0                          # 0 lead, 1 window, 2 drain
     marks = (0.0, float(seconds))      # the window's open and close
     opened_at = closed_at = None
@@ -116,11 +168,15 @@ def run(cell, seconds: float, seed: int, devices, t_start: float,
 
     def advance(now):
         nonlocal phase, opened_at, closed_at, compiled, backlog, setup_s
+        nonlocal window
         if phase == 0 and now >= marks[0]:
             setup_s = time.perf_counter() - t_start
             compiled = harness.compiles()
             if trace_dir:
                 trace_reduce.start_trace(trace_dir)
+            # made once the profiler runs: a span made before it started
+            # is never recorded
+            window = jax.profiler.TraceAnnotation(trace_reduce.WINDOW_SPAN)
             window.__enter__()
             phase, opened_at = 1, now
         if phase == 1 and now >= marks[1]:
@@ -140,7 +196,7 @@ def run(cell, seconds: float, seed: int, devices, t_start: float,
             with jax.profiler.TraceAnnotation("serve.enqueue"):
                 while pending and pending[0].due_s <= now:
                     q = pending.popleft()
-                    late = max(late, now - q.due_s)
+                    late.append(now - q.due_s)
                     r = Request(uid=q.uid, prompt=q.prompt,
                                 max_new_tokens=q.max_new)
                     try:
@@ -185,8 +241,13 @@ def run(cell, seconds: float, seed: int, devices, t_start: float,
                 qs[i] = (lens1[i] - lens0[i]) if eng.slots[i] is r \
                     else grown
                 st = eng.stats[r.uid]
-                if st.first_token_at is not None and r.uid not in first:
-                    first[r.uid] = te
+                # the pass that ends a prompt produces the first token; a
+                # decode pass appends the token fed to it and produces the
+                # next, unless the request is done with the one appended
+                if st.first_token_at is not None and r.uid not in stamps:
+                    stamps[r.uid] = [te]
+                elif grown and st.finished_at is None:
+                    stamps[r.uid].append(te)
                 if st.finished_at is not None and r.uid not in last:
                     last[r.uid] = te
             passes.append((ts, te, starts, qs))
@@ -208,13 +269,8 @@ def run(cell, seconds: float, seed: int, devices, t_start: float,
     # end-to-end numbers over every request due in the window
     gave_up = time.perf_counter() - t0
     due = {q.uid: q.due_s for q in reqs if q.due_s >= marks[0]}
-    ttft = [first.get(u, gave_up) - due[u] for u in due]
-    tpot = [(last[u] - first[u]) / (len(live[u].generated) - 1)
-            for u in due if u in last and len(live[u].generated) > 1]
-    failed = sum(1 for u in due if u in refused or u not in last)
-    e2e = {"serve_ttft_p95_s": percentile(ttft, 95),
-           "serve_tpot_p95_s": percentile(tpot, 95) if tpot else gave_up,
-           "serve_tokens_per_s": window_tokens / seconds}
+    e2e, n = latency(due, stamps, set(last), gave_up)
+    e2e["serve_tokens_per_s"] = window_tokens / seconds
 
     finished = {u: len(live[u].generated) for u in last}
     sample = gen.check_sample(finished, tr, seed)
@@ -238,15 +294,22 @@ def run(cell, seconds: float, seed: int, devices, t_start: float,
             tot[k] += c[k]
     print(f"chipbench: {len(due)} requests due in the window "
           f"({len(reqs) - len(due)} before it), "
-          f"{sum(1 for u in due if u in last)} finished, "
+          f"{len(due) - n['failed']} finished, "
           f"{sum(1 for u in due if u in refused)} refused; "
           f"{len(passes)} passes ({len(in_window)} in the window); "
-          f"requests offered at most {late:.6f} s late; "
+          f"requests offered late by {percentile(late or [0.0], 50):.6f} s "
+          f"at the median, {max(late, default=0.0):.6f} s at most; "
           f"checked {len(rows)} requests, "
           f"{sum(len(o) for _, o in rows)} served tokens",
           flush=True)
+    tail = tail_percentile(n["requests"])
+    beyond = (f"p{tail} with {n['requests'] * (100 - tail) // 100} beyond "
+              f"it" if tail else "too few for a tail")
+    print(f"chipbench: time to first token over {n['requests']} requests "
+          f"({beyond}), gaps between tokens over {n['gaps']} gaps; "
+          + ", ".join(f"{k} {v!r}" for k, v in e2e.items()), flush=True)
     return {"setup_s": setup_s, "e2e": e2e, "attempted": len(due),
-            "failed": failed, "gaps": {"served_logit_gap": gap},
+            "failed": n["failed"], "gaps": {"served_logit_gap": gap},
             "peak_bytes": peak, "units": len(in_window),
             "layer_counts": tot, "host_spans": HOST_SPANS,
             "rows": rows, "queued_at_close": backlog[0],
