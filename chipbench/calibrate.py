@@ -35,7 +35,7 @@ def _train(cell, seed, seconds, devices, sound_only=False):
 
     from chipbench import compare, weights
     from chipbench.drivers import train
-    from chipbench.reference import dasha, granite
+    from chipbench.reference import dasha
 
     out = train.run(cell, seconds, seed, devices, time.perf_counter())
     m, tcfg = cell.config["model"], cell.config["trainer"]
@@ -44,7 +44,7 @@ def _train(cell, seed, seconds, devices, sound_only=False):
         return {"sound": out["gaps"], "participants": ref["participants"],
                 "setup_s": out["setup_s"], "e2e": out["e2e"]}
     make_w = jax.jit(functools.partial(weights.make, model=m,
-                                       dtype=jnp.bfloat16))
+                                       dtype=jnp.bfloat16, arch=cell.arch))
 
     def x0():
         return make_w(weights.stream(seed, "weights"))
@@ -59,11 +59,12 @@ def _train(cell, seed, seconds, devices, sound_only=False):
                for j in range(train.CHECKED_ROUNDS)]
     keys = [jax.random.fold_in(rkeys, t)
             for t in range(train.CHECKED_ROUNDS)]
+    plain = cell.reference
     control = dasha.run_reference(m, tcfg, x0, batches, keys, n,
-                                  mm=granite.fp8_mm)
+                                  mm=plain.fp8_mm, ref=plain)
     half = dasha.run_reference(
         m, tcfg, x0, batches, keys, n,
-        tokens_view=lambda t: t[..., : t.shape[-1] // 2])
+        tokens_view=lambda t: t[..., : t.shape[-1] // 2], ref=plain)
     return {"sound": out["gaps"],
             "control": compare.train_gaps(control, ref),
             "half_batch": compare.train_gaps(half, ref),
@@ -87,9 +88,9 @@ def _serve(cell, seed, seconds, devices, sound_only=False):
                 "attempted": out["attempted"]}
     m = cell.config["model"]
     params = jax.jit(functools.partial(weights.make, model=m,
-                                       dtype=jnp.bfloat16))(
+                                       dtype=jnp.bfloat16, arch=cell.arch))(
         weights.stream(seed, "weights"))
-    control = ServedGaps(m).control(params, out["rows"])
+    control = ServedGaps(m, cell.reference).control(params, out["rows"])
     return {"sound": out["gaps"],
             "control": {"served_logit_gap": control},
             "served_tokens": sum(len(o) for _, o in out["rows"]),

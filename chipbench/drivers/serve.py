@@ -28,7 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from chipbench import counts, harness, trace_reduce, traffic as gen, weights
-from chipbench.drivers import program
 from chipbench.reference.serve_check import ServedGaps
 
 HOST_SPANS = ("engine.step", "serve.admit", "serve.enqueue", "serve.idle")
@@ -112,12 +111,13 @@ def run(cell, seconds: float, seed: int, devices, t_start: float,
 
     conf, tr = cell.config, cell.traffic
     m, ecfg = conf["model"], conf["engine"]
-    arch = program.arch(m, conf["arch"])
+    arch = cell.arch.program_arch(m, conf["arch"])
     model = Model(arch)
     weights.check_layout(jax.eval_shape(model.init_params,
-                                        jax.random.key(0)), m)
+                                        jax.random.key(0)), m, cell.arch)
     make_w = jax.jit(functools.partial(weights.make, model=m,
-                                       dtype=arch.param_dtype))
+                                       dtype=arch.param_dtype,
+                                       arch=cell.arch))
     params = make_w(weights.stream(seed, "weights"))
     eng = PagedEngine(model, params, batch_size=int(ecfg["slots"]),
                       max_seq_len=int(ecfg["max_seq_len"]),
@@ -279,7 +279,8 @@ def run(cell, seconds: float, seed: int, devices, t_start: float,
     gc.collect()
 
     params = make_w(weights.stream(seed, "weights"))
-    gap = ServedGaps(m).gaps(params, rows) if rows else float("inf")
+    gap = ServedGaps(m, cell.reference).gaps(params, rows) if rows \
+        else float("inf")
     del params
 
     in_window = [p for p in passes
@@ -287,11 +288,14 @@ def run(cell, seconds: float, seed: int, devices, t_start: float,
     tot = {"flops": 0.0, "attn_flops": 0.0, "attn_bytes": 0.0,
            "tokens": 0.0}
     kv_bytes = counts.DTYPE_BYTES[m["torch_dtype"]]
+    hook = getattr(cell.arch, "serve_layer_counts", None)
     for _, _, starts, qs in in_window:
-        c = counts.serve_pass_counts(m, int(ecfg["page_size"]), kv_bytes,
-                                     kv_bytes, starts, qs)
-        for k in tot:
-            tot[k] += c[k]
+        args = (m, int(ecfg["page_size"]), kv_bytes, kv_bytes, starts, qs)
+        c = cell.arch.serve_pass_counts(*args)
+        if hook is not None:
+            c.update(hook(*args))
+        for k, v in c.items():
+            tot[k] = tot.get(k, 0.0) + v
     print(f"chipbench: {len(due)} requests due in the window "
           f"({len(reqs) - len(due)} before it), "
           f"{len(due) - n['failed']} finished, "

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from chipbench import compare, counts, harness, trace_reduce, weights
-from chipbench.drivers import program
 from chipbench.reference import dasha as ref_dasha
 
 CHECKED_ROUNDS = 3
@@ -76,7 +75,8 @@ def run(cell, seconds: float, seed: int, devices, t_start: float,
     ref = ref_dasha.run_reference(
         m, tcfg, lambda: make_w(weights.stream(seed, "weights")),
         [b["tokens"] for b in batches[:CHECKED_ROUNDS]],
-        [_round_key(rkeys, t) for t in range(CHECKED_ROUNDS)], n)
+        [_round_key(rkeys, t) for t in range(CHECKED_ROUNDS)], n,
+        ref=cell.reference)
     out["gaps"] = compare.train_gaps(out["program"], ref)
     out["reference"] = ref
     return out
@@ -96,7 +96,7 @@ def _program(cell, seconds: float, seed: int, devices, t_start: float,
 
     conf, traffic = cell.config, cell.traffic
     m, tcfg = conf["model"], conf["trainer"]
-    arch = program.arch(m, conf["arch"])
+    arch = cell.arch.program_arch(m, conf["arch"])
     mesh = make_device_mesh(devices)
     axes = data_axes_of(mesh)
     n = num_nodes(mesh)
@@ -112,13 +112,14 @@ def _program(cell, seconds: float, seed: int, devices, t_start: float,
     trainer = Trainer(model, mesh, TrainerConfig(
         dasha=dcfg, server=paper_server(float(tcfg["gamma"]))))
     weights.check_layout(jax.eval_shape(model.init_params,
-                                        jax.random.key(0)), m)
+                                        jax.random.key(0)), m, cell.arch)
 
     # one state, on the benchmark's weights
     state = trainer.init(jax.random.key(0))
     shardings = jax.tree.map(lambda x: x.sharding, state.params)
     make_w = jax.jit(functools.partial(weights.make, model=m,
-                                       dtype=arch.param_dtype),
+                                       dtype=arch.param_dtype,
+                                       arch=cell.arch),
                      out_shardings=shardings)
     state = state._replace(params=make_w(weights.stream(seed, "weights")))
     data_key = weights.stream(seed, "data")
@@ -199,13 +200,16 @@ def _program(cell, seconds: float, seed: int, devices, t_start: float,
                for d in devices)
     tokens = rounds * n * seqs * seq_len
     units = traced if trace_dir else rounds
-    layer_counts = {
-        "model_flops": units * counts.train_round_flops(m, seq_len,
-                                                        n * seqs),
-        "dasha_bytes": units * counts.dasha_update_bytes(
-            weights.leaf_sizes(m), float(tcfg["compression_ratio"]),
-            int(tcfg["block_size"])),
+    per_round = {
+        "model_flops": cell.arch.train_round_flops(m, seq_len, n * seqs),
+        "dasha_bytes": counts.dasha_update_bytes(
+            weights.leaf_sizes(m, cell.arch),
+            float(tcfg["compression_ratio"]), int(tcfg["block_size"])),
     }
+    hook = getattr(cell.arch, "train_layer_counts", None)
+    if hook is not None:
+        per_round.update(hook(m, seq_len, n * seqs))
+    layer_counts = {k: units * v for k, v in per_round.items()}
     out = {"setup_s": setup_s,
            "e2e": {"train_tokens_per_s": tokens / elapsed},
            "attempted": rounds, "failed": 0, "peak_bytes": peak,

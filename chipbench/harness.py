@@ -6,8 +6,11 @@ A cell is an entry of ``BENCHMARK.json``'s ``workloads``.  Its files are
 found by name under the checkout's ``chipbench/``: the configuration (the file the
 ``configs`` entry names), ``traffic/<traffic>.json`` and
 ``limits/<cell>.json``; each per-layer metric is the ``read`` function
-of ``metrics/<metric>.py``.  Adding a cell or a metric adds files and
-entries; no code here changes.
+of ``metrics/<metric>.py``.  The configuration's ``model.model_type``
+names its architecture's two modules, ``archs/<model_type>.py`` (the
+program's config, the weights' layout and init, the counts) and
+``reference/<model_type>.py`` (the plain model).  Adding a cell, a
+metric or an architecture adds files and entries; no code here changes.
 """
 from __future__ import annotations
 
@@ -16,6 +19,7 @@ import importlib.util
 import json
 import os
 import sys
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -31,6 +35,13 @@ class Cell:
     end_to_end: List[Dict]
     per_layer: List[Dict]
     root: str = ROOT
+    arch: ModuleType = dataclasses.field(init=False, repr=False)
+    reference: ModuleType = dataclasses.field(init=False, repr=False)
+
+    def __post_init__(self):
+        model = self.config["model"]
+        self.arch = load_arch(model, self.root)
+        self.reference = load_reference(model, self.root)
 
     @property
     def driver(self) -> str:
@@ -69,15 +80,42 @@ def resolve(cell_name: str, root: str) -> Cell:
         root=root)
 
 
-def reader(metric: str, root: str = ROOT) -> Callable:
-    """``read(ctx)`` of ``<root>/chipbench/metrics/<metric>.py``."""
-    path = os.path.join(root, "chipbench", "metrics", metric + ".py")
+def _load(root: str, kind: str, name: str) -> ModuleType:
+    """``<root>/chipbench/<kind>/<name>.py``, executed as a module."""
+    path = os.path.join(root, "chipbench", kind, name + ".py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"no {kind} module {name!r}: {path} is "
+                                f"missing")
     spec = importlib.util.spec_from_file_location(
-        "chipbench.metrics." + metric.replace(".", "_").replace("-", "_"),
+        f"chipbench.{kind}." + name.replace(".", "_").replace("-", "_"),
         path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(metric: str, root: str = ROOT) -> Callable:
+    """``read(ctx)`` of ``<root>/chipbench/metrics/<metric>.py``."""
+    return _load(root, "metrics", metric).read
+
+
+def load_arch(model: Dict, root: str = ROOT) -> ModuleType:
+    """The program side of ``model``'s architecture,
+    ``<root>/chipbench/archs/<model_type>.py``: ``program_arch(model,
+    name)``, ``layout(model)``, ``init_leaf(path, shape, z, model,
+    dtype)``, ``train_round_flops(model, seq_len, seqs)``,
+    ``serve_pass_counts(model, page_size, kv_bytes, act_bytes, starts,
+    q_lens)`` and, optionally, ``train_layer_counts`` and
+    ``serve_layer_counts`` with the same arguments as those two, whose
+    keys the drivers add to the cell's counts."""
+    return _load(root, "archs", model["model_type"])
+
+
+def load_reference(model: Dict, root: str = ROOT) -> ModuleType:
+    """The plain model of ``model``'s architecture,
+    ``<root>/chipbench/reference/<model_type>.py``: ``loss``, ``logits``,
+    ``f32_mm`` and ``fp8_mm``.  It imports nothing of the program."""
+    return _load(root, "reference", model["model_type"])
 
 
 @dataclasses.dataclass

@@ -3,8 +3,11 @@ the Algorithm 5 oracle) with BlockRandK compression, independent
 participation and the plain server step ``x <- x - gamma g``.
 
 Written from the paper and the configuration file; it imports nothing
-of the program.  Estimators are stored at the parameters' dtype and
-updated in float32, as the configuration states.  The randomness is
+of the program.  The loss is that of the plain model of the
+configuration's ``model_type`` (``chipbench/reference/<model_type>.py``,
+the ``ref`` argument; where none is given, this checkout's module).
+Estimators are stored at the parameters' dtype and updated in float32,
+as the configuration states.  The randomness is
 the documented per-round contract of a DASHA-PP run, so that both sides
 draw the same participants and blocks from the same round key:
 
@@ -23,13 +26,14 @@ live on the chip beside the parameters.
 from __future__ import annotations
 
 import math
-from typing import Callable, Dict, List, Sequence
+from types import ModuleType
+from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench.reference import granite
+from chipbench import harness
 
 F32 = jnp.float32
 
@@ -54,13 +58,17 @@ class Reference:
     """``steps`` rounds from ``x0`` with zero-initialised estimators.
 
     ``trainer`` is the configuration's ``trainer`` section; ``mm`` the
-    matrix product (float32, or the float8 control); ``tokens_view`` maps
-    a node's (seqs, T) tokens to what its loss reads (the identity, or a
-    planted fault)."""
+    matrix product (the plain model's ``f32_mm`` where None, or the
+    float8 control); ``tokens_view`` maps a node's (seqs, T) tokens to
+    what its loss reads (the identity, or a planted fault); ``ref`` the
+    plain model's module."""
 
     def __init__(self, model: Dict, trainer: Dict, n_nodes: int,
-                 mm: Callable = granite.f32_mm,
-                 tokens_view: Callable = lambda t: t):
+                 mm: Optional[Callable] = None,
+                 tokens_view: Callable = lambda t: t,
+                 ref: Optional[ModuleType] = None):
+        ref = harness.load_reference(model) if ref is None else ref
+        mm = ref.f32_mm if mm is None else mm
         self.m, self.t, self.n = model, trainer, n_nodes
         rows = int(model.get("embedding_rows", model["vocab_size"]))
         gamma = float(trainer["gamma"])
@@ -72,8 +80,8 @@ class Reference:
         def vg(p16, toks):
             p32 = jax.tree.map(lambda x: x.astype(F32), p16)
             return jax.value_and_grad(
-                lambda p: granite.loss(p, tokens_view(toks), model, rows,
-                                       mm))(p32)
+                lambda p: ref.loss(p, tokens_view(toks), model, rows,
+                                   mm))(p32)
 
         self._vg = jax.jit(vg)
         self._advance = jax.jit(lambda x, g: jax.tree.map(
@@ -201,12 +209,12 @@ def _ready(x):
 
 def run_reference(model: Dict, trainer: Dict, make_x0: Callable,
                   batches: List, keys: List, n_nodes: int,
-                  mm: Callable = granite.f32_mm,
-                  tokens_view: Callable = lambda t: t, steps: int = 3
-                  ) -> Dict:
+                  mm: Optional[Callable] = None,
+                  tokens_view: Callable = lambda t: t, steps: int = 3,
+                  ref: Optional[ModuleType] = None) -> Dict:
     """One-shot :class:`Reference` run, at the highest matmul precision
     for float32 products; ``make_x0()`` makes the starting parameters
     on the device."""
     with jax.default_matmul_precision("highest"):
-        return Reference(model, trainer, n_nodes, mm, tokens_view).run(
-            make_x0, batches, keys, steps)
+        return Reference(model, trainer, n_nodes, mm, tokens_view,
+                         ref).run(make_x0, batches, keys, steps)

@@ -4,13 +4,14 @@ served token's logit lies below the reference's best at its position.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Sequence
+from types import ModuleType
+from typing import Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench.reference import granite
+from chipbench import harness
 
 
 def bucket(n: int, least: int = 512) -> int:
@@ -35,13 +36,16 @@ def _row(prompt: Sequence[int], served: Sequence[int]):
 class ServedGaps:
     """``gaps(params, [(prompt, served), ...])`` -> widest gap of the
     served tokens (float32 reference).  ``control`` reads, at the same
-    positions, the gap of the token that the float8 model puts first."""
+    positions, the gap of the token that the float8 model puts first.
+    ``ref`` is the plain model's module (``chipbench/reference/
+    <model_type>.py``; where None, this checkout's)."""
 
-    def __init__(self, model: Dict):
+    def __init__(self, model: Dict, ref: Optional[ModuleType] = None):
+        ref = harness.load_reference(model) if ref is None else ref
         rows = model["vocab_size"]
 
         def served(params, toks, nxt, lo, hi):
-            lg = granite.logits(params, toks, model, rows)
+            lg = ref.logits(params, toks, model, rows)
             pos = jnp.arange(toks.shape[0])
             ok = (pos >= lo) & (pos < hi)
             got = jnp.take_along_axis(lg, nxt[:, None], 1)[:, 0]
@@ -49,8 +53,8 @@ class ServedGaps:
 
         def control(params, toks, nxt, lo, hi):
             del nxt
-            lg = granite.logits(params, toks, model, rows)
-            lg8 = granite.logits(params, toks, model, rows, granite.fp8_mm)
+            lg = ref.logits(params, toks, model, rows)
+            lg8 = ref.logits(params, toks, model, rows, ref.fp8_mm)
             pick = jnp.argmax(lg8, axis=-1)
             pos = jnp.arange(toks.shape[0])
             ok = (pos >= lo) & (pos < hi)

@@ -1,5 +1,6 @@
-"""Operations and bytes of ``chipbench.counts``, against sums worked out
-by hand from the configurations' shapes."""
+"""Operations and bytes of ``chipbench.counts`` and of Granite's
+``chipbench/archs/granite.py``, against sums worked out by hand from the
+configurations' shapes."""
 from __future__ import annotations
 
 import json
@@ -8,6 +9,7 @@ import os
 import pytest
 
 from chipbench import counts, weights
+from chipbench.archs import granite
 
 CONFIGS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "configs")
@@ -22,22 +24,22 @@ def test_matmul_params_of_granite():
     m = _model("granite-3-2b.train-d8")
     # q and o 2048x2048, k and v 2048x512, three MLP matrices 2048x8192
     per_layer = 2 * 2048 * 2048 + 2 * 2048 * 512 + 3 * 2048 * 8192
-    assert counts.layer_matmul_params(m) == per_layer
-    assert counts.matmul_params(m) == 8 * per_layer + 49155 * 2048
+    assert granite.layer_matmul_params(m) == per_layer
+    assert granite.matmul_params(m) == 8 * per_layer + 49155 * 2048
 
 
 @pytest.mark.parametrize("seq_len,tflop", [(4096, 32.16), (512, 3.659)])
 def test_train_round_flops(seq_len, tflop):
     m = _model("granite-3-2b.train-d8")
-    n = counts.matmul_params(m)
+    n = granite.matmul_params(m)
     keys = seq_len * (seq_len + 1) // 2          # causal: 1 + 2 + ... + T
     attn = 4 * 32 * 64 * keys * 8
     want = 2 * (6 * n * seq_len + 3 * attn)      # the MVR pair, fwd + bwd
-    got = counts.train_round_flops(m, seq_len, 1)
+    got = granite.train_round_flops(m, seq_len, 1)
     assert got == pytest.approx(want, rel=1e-12)
     assert got / 1e12 == pytest.approx(tflop, rel=2e-3)
     # nodes' sequences add up
-    assert counts.train_round_flops(m, seq_len, 4) == pytest.approx(4 * got)
+    assert granite.train_round_flops(m, seq_len, 4) == pytest.approx(4 * got)
 
 
 def test_dasha_bytes_at_stored_dtype():
@@ -59,15 +61,15 @@ def test_serve_pass_counts_read_live_pages_only():
     kv_page = 2 * 16 * 8 * 64 * 2               # K and V of one bf16 page
     # a prefill chunk of 20 tokens from 0 (2 pages) and one decode token
     # after 100 (101 tokens: 7 pages); slot 2 idle
-    c = counts.serve_pass_counts(m, 16, 2, 2, [0, 100, 0], [20, 1, 0])
+    c = granite.serve_pass_counts(m, 16, 2, 2, [0, 100, 0], [20, 1, 0])
     qo = 2 * 21 * 32 * 64 * 2
     assert c["attn_bytes"] == 40 * ((2 + 7) * kv_page + qo)
     keys = (20 * 1 + 20 * 19 // 2) + 101
     assert c["attn_flops"] == 4 * 32 * 64 * keys * 40
     assert c["tokens"] == 21
-    assert c["flops"] == (2 * 40 * counts.layer_matmul_params(m) * 21
+    assert c["flops"] == (2 * 40 * granite.layer_matmul_params(m) * 21
                           + 2 * 49155 * 2048 * 2 + c["attn_flops"])
     # the pool's size does not enter: an idle slot adds nothing
-    again = counts.serve_pass_counts(m, 16, 2, 2, [0, 100, 4000],
-                                     [20, 1, 0])
+    again = granite.serve_pass_counts(m, 16, 2, 2, [0, 100, 4000],
+                                      [20, 1, 0])
     assert again == c
