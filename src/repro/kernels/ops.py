@@ -204,13 +204,13 @@ def paged_attention_op(q: Array, k_pages: Array, v_pages: Array,
                        interpret: bool | None = None) -> Array:
     """Paged-attention decode read (DESIGN.md §11): online softmax over
     the pool pages selected by each slot's page-table row.  q (B, H,
-    hd), pages (NP, P, kvH, hd), table (B, M), lens (B,) valid tokens
-    per slot including the one just written.  Returns (B, H, hd) f32."""
+    hd), pages (NP, P, kvH * hd) or (NP, P, kvH, hd) read as stored,
+    table (B, M), lens (B,) valid tokens per slot including the one
+    just written.  Returns (B, H, hd) f32."""
     interp = _interpret_default() if interpret is None else interpret
     return paged_attention_pallas(
-        q.astype(jnp.float32), k_pages.astype(jnp.float32),
-        v_pages.astype(jnp.float32), page_table.astype(jnp.int32),
-        lens.astype(jnp.int32),
+        q.astype(jnp.float32), k_pages, v_pages,
+        page_table.astype(jnp.int32), lens.astype(jnp.int32),
         window=None if window is None else int(window), interpret=interp)
 
 
@@ -225,13 +225,14 @@ def paged_attention_batched_op(q: Array, k_pages: Array, v_pages: Array,
     carrying up to C queries (chunked prefill folds prompt chunks into
     the same launch as single-token decode).  q (B, C, H, hd), start
     (B,) tokens per slot BEFORE this pass's writes, q_lens (B,) valid
-    queries per slot.  Returns (B, C, H, hd) f32; rows ``c >= q_lens``
-    are garbage by contract."""
+    queries per slot; pages (NP, P, kvH * hd) or (NP, P, kvH, hd), read
+    as stored (no cast or relayout of the pool).  Returns (B, C, H, hd)
+    f32; rows ``c >= q_lens`` are garbage by contract."""
     interp = _interpret_default() if interpret is None else interpret
     return paged_attention_batched_pallas(
-        q.astype(jnp.float32), k_pages.astype(jnp.float32),
-        v_pages.astype(jnp.float32), page_table.astype(jnp.int32),
-        start.astype(jnp.int32), q_lens.astype(jnp.int32),
+        q.astype(jnp.float32), k_pages, v_pages,
+        page_table.astype(jnp.int32), start.astype(jnp.int32),
+        q_lens.astype(jnp.int32),
         window=None if window is None else int(window), interpret=interp)
 
 

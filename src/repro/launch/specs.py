@@ -132,31 +132,23 @@ def paged_state_specs(state_shapes: Any, mesh: Mesh,
                       num_layers: Optional[int] = None) -> Any:
     """Sharding for PagedDecodeState trees (DESIGN.md §11).  Walks by
     CACHE TYPE, not shape heuristics: pool leaves (KVCache (…, NP, P,
-    kvH, hd), MLACache (…, NP, P, r)) replicate their page dims over
+    kvH * hd), MLACache (…, NP, P, r)) replicate their page dims over
     'data' (any slot reads any page — sharding pages over data would
     all-gather the pool every step) and shard only the trailing
-    feature dim(s) over 'model'; everything else (recurrent SSM
+    feature dim over 'model'; everything else (recurrent SSM
     states, the (B, M) table, (B,) lens) takes the dense
     :func:`decode_state_specs` batch-over-'data' rule."""
-    from repro.models.layers import KVCache
     from repro.models.model import map_cache_tree
     n_model = mesh.shape["model"]
 
-    def pool_spec(leaf, feature_dims: int):
+    def pool_spec(leaf):
         dims = [None] * leaf.ndim
-        cands = sorted(range(leaf.ndim - feature_dims, leaf.ndim),
-                       key=lambda i: -leaf.shape[i])
-        for i in cands:
-            if leaf.shape[i] % n_model == 0 and leaf.shape[i] >= n_model:
-                dims[i] = "model"
-                break
+        if leaf.shape[-1] % n_model == 0 and leaf.shape[-1] >= n_model:
+            dims[-1] = "model"
         return P(*dims)
 
     def attn_spec(c):
-        # KVCache leaves end in (kvH, hd); MLACache latent/rope in one
-        # feature dim
-        fd = 2 if isinstance(c, KVCache) else 1
-        return type(c)(*(pool_spec(leaf, fd) for leaf in c))
+        return type(c)(*(pool_spec(leaf) for leaf in c))
 
     return map_cache_tree(
         state_shapes, on_attention=attn_spec,

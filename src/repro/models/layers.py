@@ -301,7 +301,7 @@ def attention_block(p: dict, x: Array, positions: Array, cfg,
     prefill isolation fix).
 
     Paged decode (``paged_table`` given, DESIGN.md §11): ``cache``
-    holds POOL pages (NP, P, kvH, hd) instead of per-slot rows; the new
+    holds POOL pages (NP, P, kvH * hd) instead of per-slot rows; the new
     KV is written at page ``paged_table[b, pos // P]`` slot ``pos % P``
     and the read attends the slot's gathered pages (jnp gather, or the
     Pallas paged-attention kernel when ``paged_kernel``).  Requires
@@ -348,18 +348,20 @@ def attention_block(p: dict, x: Array, positions: Array, cfg,
                                   jnp.minimum(pos_mat // P, M - 1), axis=1)
         pid = jnp.where(jnp.arange(T)[None] < qlens[:, None], pid, NP)
         slot = pos_mat % P
-        k_new = cache.k.at[pid, slot].set(k.astype(cache.k.dtype),
-                                          mode="drop")
-        v_new = cache.v.at[pid, slot].set(v.astype(cache.v.dtype),
-                                          mode="drop")
+        lanes = cache.k.shape[2:]          # (kvH * hd,): heads merged
+        k_new = cache.k.at[pid, slot].set(
+            k.reshape((B, T) + lanes).astype(cache.k.dtype), mode="drop")
+        v_new = cache.v.at[pid, slot].set(
+            v.reshape((B, T) + lanes).astype(cache.v.dtype), mode="drop")
         if paged_kernel:
             from repro.kernels.ops import paged_attention_batched_op
             out = paged_attention_batched_op(
                 q, k_new, v_new, paged_table, start, qlens,
                 window=cfg.attention_window).astype(v.dtype)
         else:
-            kg = paged_gather(k_new, paged_table)           # (B, M*P, ...)
-            vg = paged_gather(v_new, paged_table)
+            S = M * P
+            kg = paged_gather(k_new, paged_table).reshape(B, S, -1, hd)
+            vg = paged_gather(v_new, paged_table).reshape(B, S, -1, hd)
             k_pos = jnp.broadcast_to(jnp.arange(kg.shape[1])[None],
                                      (B, kg.shape[1]))
             mask = decode_attention_mask(pos_mat, k_pos, causal,

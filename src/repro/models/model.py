@@ -557,12 +557,14 @@ class Model:
         """One layer's cache with attention leaves laid out as POOL pages
         (num_pages, page_size, ...); recurrent leaves stay (B, ...)."""
         cfg = self.cfg
+        # KV pages store the heads merged, (NP, P, kvH * hd): minor
+        # dims a TPU tiles as they are, so the paged kernel reads the
+        # pool as stored (a (…, kvH, hd) pool is laid out otherwise on
+        # the chip, and its merged view would be a copy)
+        kv_pages = (num_pages, page_size, cfg.num_kv_heads * cfg.hd)
         if kind in ("dense", "vlm", "hybrid"):
-            kv = KVCache(
-                k=jnp.zeros((num_pages, page_size, cfg.num_kv_heads,
-                             cfg.hd), dtype),
-                v=jnp.zeros((num_pages, page_size, cfg.num_kv_heads,
-                             cfg.hd), dtype))
+            kv = KVCache(k=jnp.zeros(kv_pages, dtype),
+                         v=jnp.zeros(kv_pages, dtype))
             if kind == "hybrid":
                 return (kv, ssm_lib.mamba_init_state(cfg, batch, dtype=dtype))
             return kv
@@ -574,11 +576,8 @@ class Model:
                                    dtype),
                     k_rope=jnp.zeros((num_pages, page_size,
                                       a.qk_rope_head_dim), dtype))
-            return KVCache(
-                k=jnp.zeros((num_pages, page_size, cfg.num_kv_heads,
-                             cfg.hd), dtype),
-                v=jnp.zeros((num_pages, page_size, cfg.num_kv_heads,
-                             cfg.hd), dtype))
+            return KVCache(k=jnp.zeros(kv_pages, dtype),
+                           v=jnp.zeros(kv_pages, dtype))
         if kind == "mlstm":
             return ssm_lib.mlstm_init_state(cfg, batch)
         if kind == "slstm":
@@ -725,12 +724,18 @@ class Model:
             return pid, pos % P
 
         def pages_write(pages, seq):
+            # the prefill's (…, T, kvH, hd) rows merge into the pool's
+            # trailing feature dim
             if scan:                       # (L, NP, P, ...) <- (L, 1, T, ...)
-                pid, sl = page_idx(seq.shape[2], pages.shape[1])
-                return pages.at[:, pid, sl].set(
-                    seq[:, 0].astype(pages.dtype), mode="drop")
-            pid, sl = page_idx(seq.shape[1], pages.shape[0])
-            return pages.at[pid, sl].set(seq[0].astype(pages.dtype),
+                T = seq.shape[2]
+                pid, sl = page_idx(T, pages.shape[1])
+                rows = seq[:, 0].reshape((seq.shape[0], T) + pages.shape[3:])
+                return pages.at[:, pid, sl].set(rows.astype(pages.dtype),
+                                                mode="drop")
+            T = seq.shape[1]
+            pid, sl = page_idx(T, pages.shape[0])
+            rows = seq[0].reshape((T,) + pages.shape[2:])
+            return pages.at[pid, sl].set(rows.astype(pages.dtype),
                                          mode="drop")
 
         def recurrent_write(cur, new):

@@ -670,6 +670,11 @@ class PagedEngine:
                 tokens[i, :n] = self._pending[i][lo:lo + n]
 
         W = self._table_width()
+        # pages the paged kernel reads: each fed slot's table up to the
+        # last position any of its C query rows attends
+        fed = q_lens > 0
+        pages_walked = int(np.minimum(
+            -(-(self._lens[fed] + C) // self.page_size), W).sum())
         # snapshot the live numpy buffers: asarray may alias them while
         # the dispatch is in flight, and _ensure_capacity / the per-slot
         # length bumps below mutate both before it resolves
@@ -695,7 +700,8 @@ class PagedEngine:
             self.mixed_passes += 1
             self.prefill_forwards += 1
         sp.set(clock=self.clock, width=W, active=len(active_idx),
-               tokens=int(q_lens.sum()), pure_decode=pure_decode)
+               tokens=int(q_lens.sum()), pure_decode=pure_decode,
+               pages_walked=pages_walked)
         obs_trace.counter("pool.pages_live", self.pool.in_use,
                           track="serve")
 

@@ -221,6 +221,30 @@ def test_ttft_stamped_at_first_logit_not_admission():
     assert bulk.stats[0].ttft == 1
 
 
+def test_serve_pass_span_counts_pages_walked():
+    """Each ``serve.pass`` span carries ``pages_walked``: over the fed
+    slots, the table pages up to ``ceil((start + C) / P)``, capped at
+    the pass's width — the pages the paged kernel reads.  A 7-token
+    prompt in chunks of 2 over pages of 4, then 3 decode passes (the
+    last two on a 4-page table once the answer needs a third page)."""
+    from repro.obs import trace as obs_trace
+
+    cfg, model, params = _model()
+    tracer = obs_trace.configure()
+    try:
+        eng = PagedEngine(model, params, batch_size=2, max_seq_len=32,
+                          page_size=4, prefill_chunk_tokens=2)
+        eng.run([Request(uid=0, prompt=[5, 9, 3, 7, 2, 8, 4],
+                         max_new_tokens=3)])
+    finally:
+        obs_trace.uninstall()
+    passes = [(e["args"]["width"], e["args"]["pages_walked"])
+              for e in tracer.events if e["name"] == "serve.pass"]
+    # chunk passes start at 0, 2, 4, 6 (C = 2); decode at 7, 8, 9
+    assert passes == [(2, 1), (2, 1), (2, 2), (2, 2), (2, 2), (4, 3),
+                      (4, 3)]
+
+
 def test_ttft_percentiles_reflect_queueing():
     """Requests beyond the batch wait in the queue; their TTFT includes
     the wait, so p95 > p50 on an oversubscribed workload and every
@@ -240,30 +264,87 @@ def test_ttft_percentiles_reflect_queueing():
 # fused batched kernels vs jnp oracles
 # ----------------------------------------------------------------------
 
+# Shapes the batched GQA kernel adapts to: the pool's dtype, idle slots
+# (q_lens == 0), a table wider than every slot's live pages (dead
+# blocks: 72 pages of 4 make three 32-page blocks, and starts stay in
+# the first two), and kvH * hd lanes of 16, 128 and 256 (two 128-lane
+# tiles of two heads each, walked by the kernel's head loop).
+GQA_CASES = {
+    "f32_pool": dict(),
+    "bf16_pool": dict(dtype=jnp.bfloat16),
+    "idle_slots": dict(B=4, NP=24, idle=True),
+    "dead_blocks": dict(M=72, NP=152, start_hi=150),
+    "lanes128": dict(hd=64),
+    "lanes256": dict(H=8, kvh=4, hd=64),
+}
+
+
+def _gqa_inputs(seed, B=2, C=3, H=4, kvh=2, hd=8, P=4, NP=16, M=4,
+                dtype=jnp.float32, idle=False, start_hi=None):
+    key = jax.random.key(seed)
+    mk = lambda i, s: jax.random.normal(jax.random.fold_in(key, i), s)
+    q = mk(0, (B, C, H, hd))
+    k = mk(1, (NP, P, kvh, hd)).astype(dtype)
+    v = mk(2, (NP, P, kvh, hd)).astype(dtype)
+    rng = np.random.default_rng(seed)
+    table = jnp.asarray(rng.permutation(NP)[:B * M].reshape(B, M), jnp.int32)
+    start = rng.integers(0, (start_hi or M * P - C) + 1, B)
+    q_lens = rng.integers(1, C + 1, B)
+    if idle:
+        q_lens[rng.integers(0, B)] = 0
+        q_lens[rng.random(B) < 0.3] = 0
+    return (q, k, v, table, jnp.asarray(start, jnp.int32),
+            jnp.asarray(q_lens, jnp.int32))
+
+
+@pytest.mark.parametrize("case", sorted(GQA_CASES))
 @settings(max_examples=6, deadline=None)   # first example compiles
 @given(seed=st.integers(0, 1000), windowed=st.booleans())
-def test_batched_paged_attention_kernel_matches_ref(seed, windowed):
+def test_batched_paged_attention_kernel_matches_ref(case, seed, windowed):
     """The multi-query GQA launch on random page tables, starts, and
     chunk widths.  Padding rows (c >= q_lens) compute the same
     position-(start+c) attention in kernel and oracle — the engine
-    ignores them via drop-routed writes, so full-array comparison is
-    valid here."""
-    key = jax.random.key(seed)
-    B, C, H, kvh, hd, P, NP, M = 2, 3, 4, 2, 8, 4, 16, 4
-    mk = lambda i, s: jax.random.normal(jax.random.fold_in(key, i), s)
-    q = mk(0, (B, C, H, hd))
-    k = mk(1, (NP, P, kvh, hd))
-    v = mk(2, (NP, P, kvh, hd))
-    rng = np.random.default_rng(seed)
-    table = jnp.asarray(rng.permutation(NP)[:B * M].reshape(B, M), jnp.int32)
-    start = jnp.asarray(rng.integers(0, M * P - C + 1, B), jnp.int32)
-    q_lens = jnp.asarray(rng.integers(1, C + 1, B), jnp.int32)
+    ignores them via drop-routed writes, so every row of a slot that
+    is fed is compared; an idle slot's rows are garbage by contract."""
+    q, k, v, table, start, q_lens = _gqa_inputs(seed, **GQA_CASES[case])
     window = 5 if windowed else None
     ref = paged_attention_batched_ref(q, k, v, table, start, q_lens,
                                       window=window)
     out = paged_attention_batched_op(q, k, v, table, start, q_lens,
                                      window=window, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+    fed = np.asarray(q_lens) > 0
+    np.testing.assert_allclose(np.asarray(out)[fed], np.asarray(ref)[fed],
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("windowed", [False, True])
+def test_batched_paged_attention_never_reads_dead_pages(windowed):
+    """Pages past each fed slot's ``ceil((start + C) / P)``, the pages
+    of an idle slot and pages in no table hold NaN: the kernel must
+    skip them (DMA and compute), so every row of a fed slot is finite
+    and equals the oracle's on the clean pool."""
+    B, C, H, kvh, hd, P, M = 4, 3, 4, 2, 8, 4, 40   # two 32-page blocks
+    NP = B * M + 8
+    q, k, v, table, _, _ = _gqa_inputs(0, B=B, C=C, H=H, kvh=kvh, hd=hd,
+                                       P=P, NP=NP, M=M,
+                                       dtype=jnp.bfloat16)
+    start = jnp.asarray([5, 100, 130, 0], jnp.int32)
+    q_lens = jnp.asarray([3, 0, 1, 2], jnp.int32)
+    live = np.minimum(-(-(np.asarray(start) + C) // P), M)
+    read = np.zeros((NP,), bool)
+    for b in np.flatnonzero(np.asarray(q_lens)):
+        read[np.asarray(table)[b, :live[b]]] = True
+    dead = jnp.asarray(~read)[:, None, None, None]
+    window = 5 if windowed else None
+    ref = paged_attention_batched_ref(q, k, v, table, start, q_lens,
+                                      window=window)
+    out = paged_attention_batched_op(
+        q, jnp.where(dead, jnp.nan, k).astype(k.dtype),
+        jnp.where(dead, jnp.nan, v).astype(v.dtype), table, start, q_lens,
+        window=window, interpret=True)
+    fed = np.asarray(q_lens) > 0
+    assert np.isfinite(np.asarray(out)[fed]).all()
+    np.testing.assert_allclose(np.asarray(out)[fed], np.asarray(ref)[fed],
                                rtol=1e-5, atol=1e-5)
 
 

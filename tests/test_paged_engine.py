@@ -23,7 +23,7 @@ import pytest
 from _hypo import given, settings, st
 
 from repro.kernels.ops import paged_attention_op
-from repro.kernels.paged_attention import (paged_attention_ref,
+from repro.kernels.paged_attention import (_block_rows, paged_attention_ref,
                                            paged_attention_vmem_bytes)
 from repro.models import Model, get_smoke_config
 from repro.serving import DecodeServer, PagedEngine, Request
@@ -259,6 +259,23 @@ def test_paged_attention_kernel_matches_ref(seed, page_size, windowed):
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("page_size,width,lanes,itemsize,rows", [
+    (16, 16, 512, 2, 128),     # granite-3-2b.serve: 8 pages of 16 a step
+    (16, 4, 512, 2, 64),       # capped at a narrow table
+    (2, 128, 16, 4, 128),      # many small pages make the block
+    (4096, 32, 1024, 2, 512),  # a page past the budget: sub-page tiles
+])
+def test_gqa_block_rows_follow_the_shapes(page_size, width, lanes,
+                                          itemsize, rows):
+    """The GQA kernel's step reads whole pages making >= 128 rows,
+    capped at the table width and at the VMEM budget of double-buffered
+    K and V blocks as stored; a page past the budget alone is cut into
+    tiles of whole sublane tiles."""
+    got = _block_rows(page_size, width, lanes, itemsize)
+    assert got == rows
+    assert 2 * 2 * got * lanes * itemsize <= (4 << 20)
+
+
 def test_paged_state_specs_replicate_pages_shard_heads():
     """Production placement rule (launch/specs.paged_state_specs): pool
     page dims replicate over 'data' (any slot reads any page), only the
@@ -272,17 +289,17 @@ def test_paged_state_specs_replicate_pages_shard_heads():
 
     mesh = SimpleNamespace(shape={"data": 4, "model": 4},
                            axis_names=("data", "model"))
-    kv = KVCache(k=jax.ShapeDtypeStruct((64, 16, 4, 32), jnp.float32),
-                 v=jax.ShapeDtypeStruct((64, 16, 4, 32), jnp.float32))
+    kv = KVCache(k=jax.ShapeDtypeStruct((64, 16, 4 * 32), jnp.float32),
+                 v=jax.ShapeDtypeStruct((64, 16, 4 * 32), jnp.float32))
     mla = MLACache(c_kv=jax.ShapeDtypeStruct((64, 16, 32), jnp.float32),
                    k_rope=jax.ShapeDtypeStruct((64, 16, 16), jnp.float32))
     recurrent = jax.ShapeDtypeStruct((8, 6, 24), jnp.float32)   # (B, ...)
     table = jax.ShapeDtypeStruct((8, 5), jnp.int32)
     specs = paged_state_specs(((kv, recurrent), mla, table), mesh)
     (kv_s, rec_s), mla_s, table_s = specs
-    # hd=32 shards over 'model'; the (NP=64, P=16) page dims never
-    # shard even though both divide the data axis
-    assert kv_s.k == P(None, None, None, "model")
+    # the merged kvH * hd = 128 shards over 'model'; the (NP=64, P=16)
+    # page dims never shard even though both divide the data axis
+    assert kv_s.k == P(None, None, "model")
     assert mla_s.c_kv == P(None, None, "model")  # latent rank only
     assert rec_s == P("data", None, "model")     # dense batch rule
     assert table_s == P("data", None)

@@ -12,6 +12,9 @@ The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and test workers
 that each import this file must all collect the same tests.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -26,9 +29,13 @@ from repro.kernels import paged_attention as pa
 pytestmark = pytest.mark.filterwarnings(
     "ignore:Error reading persistent compilation cache entry:UserWarning")
 
-F32, I32 = jnp.float32, jnp.int32
+F32, I32, BF16 = jnp.float32, jnp.int32, jnp.bfloat16
 D = 2048 * 8192          # one granite-3-2b MLP matrix, flattened
 HP = dict(b=0.3, a=0.1, pa=0.5)
+# serve-alpaca's paged pass: 32 slots, 32 query / 8 kv heads of 64, a
+# pool of 1024 pages of 16 stored bf16 with the heads merged, tables
+# of up to 32 pages
+SERVE = dict(B=32, H=32, kvh=8, hd=64, P=16, NP=1024, M=32)
 
 
 @pytest.fixture(scope="module")
@@ -70,7 +77,22 @@ def _kernel_cases():
                  ((NP, P, kvh, hd), F32), ((B, M), I32), ((B,), I32),
                  ((B,), I32)])
 
+    def merged_gqa(c, B, H, kvh, hd, P, NP, M):
+        pool = ((NP, P, kvh * hd), BF16)
+        return (lambda q, k, v, t, s, ql: pa.paged_attention_batched_pallas(
+                    q, k, v, t, s, ql, interpret=False),
+                [((B, c, H, hd), F32), pool, pool, ((B, M), I32),
+                 ((B,), I32), ((B,), I32)])
+
     return {
+        "paged_gqa_serve_decode": merged_gqa(1, **SERVE),
+        "paged_gqa_serve_chunk64": merged_gqa(64, **SERVE),
+        # other shapes the kernel adapts to: one 128-lane tile of two
+        # heads, heads of 128 lanes each, a page past the VMEM budget
+        # (walked in sub-page tiles)
+        "paged_gqa_lanes128": merged_gqa(4, 8, 8, 2, 64, 16, 256, 32),
+        "paged_gqa_hd128": merged_gqa(4, 8, 32, 8, 128, 16, 256, 32),
+        "paged_gqa_big_pages": merged_gqa(4, 4, 32, 8, 128, 4096, 8, 2),
         "dasha_update_batched": (
             lambda gn, go, h, gi, m: du.dasha_update_batched_pallas(
                 gn, go, h, gi, m, interpret=False, **HP),
@@ -128,3 +150,82 @@ def test_training_attention_compiles_to_flash_kernels(one_chip, monkeypatch):
     for phase in ("fwd", "dkv"):
         assert f"splash_mqa_{phase}" in text
     assert " while(" not in text
+
+
+
+def _assert_pool_as_stored(text: str) -> None:
+    """Every value of the compiled text that holds a layer pool's
+    elements is the stored bf16 pool, ``(NP, P, kvH * hd)`` or its
+    ``(NP * P, kvH * hd)`` rows, in row-major order (memory-space moves
+    and views aside), and none is a transpose or a convert: no f32 and
+    no head-major copy of the pool exists."""
+    g = SERVE
+    lanes = g["kvh"] * g["hd"]
+    views = {(g["NP"], g["P"], lanes), (g["NP"] * g["P"], lanes)}
+    seen = 0
+    for m in re.finditer(r"= (\w+)\[([\d,]+)\]\{([\d,]*)[:}]\S*\s+"
+                         r"([\w-]+)\(", text):
+        dims = tuple(int(d) for d in m.group(2).split(","))
+        if math.prod(dims) != g["NP"] * g["P"] * lanes:
+            continue
+        seen += 1
+        dtype, order, op = m.group(1), m.group(3), m.group(4)
+        assert dtype == "bf16" and dims in views, (op, dtype, dims)
+        assert order == ",".join(map(str, reversed(range(len(dims))))), \
+            (op, dims, order)
+        assert op not in ("transpose", "convert"), (op, dims)
+    assert seen >= 2   # the K and V pools themselves
+
+
+@pytest.mark.parametrize("name", ["paged_gqa_serve_decode",
+                                  "paged_gqa_serve_chunk64"])
+def test_paged_gqa_kernel_reads_the_pool_as_stored(one_chip, name):
+    """At serve-alpaca's shapes the kernel takes the bf16 pool as the
+    engine stores it: nothing is made of it around the launch — no f32
+    cast, no head-major transpose, no relayout copy."""
+    fn, shapes = _kernel_cases()[name]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    text = _compile(fn, *args).as_text()
+    assert "tpu_custom_call" in text
+    _assert_pool_as_stored(text)
+
+
+@pytest.mark.parametrize("chunk", [1, 64])
+def test_paged_serve_layer_keeps_the_pool_as_stored(one_chip, monkeypatch,
+                                                    chunk):
+    """One paged attention layer of the serve pass at granite-3-2b's
+    widths on the pool as the model stores it (the KV write into the
+    donated pool, then the kernel's read), compiled for a described
+    v5e, makes no f32 or head-major copy of the pool."""
+    from repro.models import Model, get_config
+    from repro.models.layers import KVCache, attention_block, init_attention
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    cfg = get_config("granite-3-2b")
+    g = SERVE
+    assert (cfg.num_heads, cfg.num_kv_heads, cfg.hd) == (g["H"], g["kvh"],
+                                                         g["hd"])
+    model = Model(cfg)
+    state = jax.eval_shape(lambda: model.init_paged_state(
+        g["B"], g["NP"], g["P"], g["M"]))
+    stored = jax.tree.leaves(state.caches)[0]   # one layer's K pool
+    stored_shape = stored.shape[1:] if model.scan else stored.shape
+
+    def layer(p, x, cache, table, start, q_lens):
+        pos = start[:, None] + jnp.arange(chunk)[None]
+        return attention_block(p, x, pos, cfg, cache=cache, cache_pos=start,
+                               paged_table=table, paged_kernel=True,
+                               q_lens=q_lens)
+
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    pool = sds(stored_shape, stored.dtype)
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        lambda: init_attention(jax.random.key(0), cfg)))
+    args = (params, sds((g["B"], chunk, cfg.d_model), BF16),
+            KVCache(k=pool, v=pool), sds((g["B"], g["M"]), I32),
+            sds((g["B"],), I32), sds((g["B"],), I32))
+    text = jax.jit(layer, donate_argnums=(2,)).lower(*args).compile() \
+        .as_text()
+    assert "paged_attention_batched" in text
+    _assert_pool_as_stored(text)
